@@ -1,6 +1,11 @@
 """Sharded-serving benchmark (EXPERIMENTS.md §Sharded-serving, gate 5):
 tensor-parallel paged decode on a forced-host-device mesh.
 
+A CPU-only structural gate: it must be run with JAX_PLATFORMS=cpu and
+refuses to start otherwise. Its numbers come from four host devices that
+share one CPU, so it says nothing about chips; the tensor-parallel path
+on real chips is checked by ``chip_smoke.py --four-chips``.
+
 Shards the paged engine 2- and 4-way over KV heads (DESIGN.md §9) and
 drives the SAME workload on a single-device twin, asserting the
 equivalence contract the test harness enforces (logits < 1e-5), zero page
@@ -14,7 +19,7 @@ jax with the default single CPU device, and
 ``--xla_force_host_platform_device_count`` only takes effect before first
 backend init. The worker re-execs this module with XLA_FLAGS forced.
 
-  PYTHONPATH=src python -m benchmarks.sharded_serving [--tiny]
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.sharded_serving [--tiny]
 """
 from __future__ import annotations
 
@@ -120,12 +125,16 @@ def run(tiny: bool = False, engine: bool = True) -> None:
     """Re-exec in a worker with the device count forced (see module doc).
     ``engine`` is accepted for harness symmetry; the bench IS the engine
     measurement, tiny by construction, so it always runs."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise SystemExit("sharded_serving is a CPU-only structural gate on "
+                         "forced host devices: run it with "
+                         "JAX_PLATFORMS=cpu (chip_smoke.py --four-chips "
+                         "checks the sharded path on real chips)")
     env = dict(os.environ)
     flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
                    env.get("XLA_FLAGS", "")).strip()
     env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count="
                         f"{N_DEVICES}").strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.sharded_serving", "--worker"]
         + (["--tiny"] if tiny else []), env=env)

@@ -1,8 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs in Python for correctness validation; on TPU they compile to
-Mosaic. ``interpret=None`` auto-selects by backend.
+On the CPU backend the kernels execute in interpret mode — the kernel body
+runs in Python for correctness validation; on TPU they compile to Mosaic.
+``interpret=None`` selects by backend, and any other backend is an error
+rather than a silent interpret-mode run.
 """
 from __future__ import annotations
 
@@ -21,9 +22,13 @@ from repro.kernels import ssd_scan as _ssd
 
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels compile for TPU and interpret "
+                           f"on CPU; backend {backend!r} is neither")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("window", "blk", "interpret"))

@@ -1,11 +1,21 @@
 """Serving launcher: run the SLICE-scheduled engine for any --arch.
 
-On this CPU container it runs the reduced config on the real JAX engine; on
-a TPU mesh the same entry point shards the full config over the production
-mesh (see dryrun.py for the lowering proof).
+By default it serves the reduced config (2 layers, d<=256), which is what
+the CPU backend can run (``JAX_PLATFORMS=cpu``, Pallas kernels in
+interpret mode). ``--no-reduced`` serves the published widths; on a TPU
+chip that is the normal way to run it (see chip_smoke.py at the repo root
+for the one-chip and four-chip checks). ``--mesh-shape`` shards the paged
+engine over a (data, model) mesh of the local devices.
+
+The persistent compilation cache lives where JAX_COMPILATION_CACHE_DIR
+says; when that is unset, at ``<checkout>/.jax_cache``.
 
   PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
       --scheduler slice --rate 1.0 --duration 30
+
+  # full published width on one TPU chip, paged engine
+  PYTHONPATH=src python -m repro.launch.serve --no-reduced \
+      --executor paged --pages 1024 --slots 16
 
   # paged KV arena + memory-aware SLICE admission (DESIGN.md §3 adapt. #2):
   PYTHONPATH=src python -m repro.launch.serve --executor paged \
@@ -62,6 +72,35 @@ mesh (see dryrun.py for the lowering proof).
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
+from pathlib import Path
+from typing import Any, Dict, List, Optional, Sequence
+
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+@dataclasses.dataclass
+class ServeRun:
+    """What one ``main`` call served: the engines it built (one per fleet
+    instance), the serving loop's result and ``metrics.summarize`` of its
+    tasks."""
+    executors: List[Any]
+    result: Any
+    summary: Dict[str, Any]
+
+
+def configure_compile_cache(checkout: Path = CHECKOUT) -> None:
+    """Keep JAX's persistent compilation cache at ``<checkout>/.jax_cache``
+    unless JAX_COMPILATION_CACHE_DIR is set, in which case JAX reads the
+    variable itself and nothing is set here. The path is fixed so a later
+    run in the same checkout finds what this one compiled. Entry points
+    call this; importing the library never does."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    jax.config.update("jax_compilation_cache_dir",
+                      str(Path(checkout) / ".jax_cache"))
 
 
 def _make_trace(args):
@@ -182,9 +221,14 @@ def _run_fleet(args):
               f"admitted={res.admissions.get(name, 0)} SLO={a.slo:.1%}")
     if tr is not None:
         _export_trace(tr, args, res.tasks, tr.events)
+    return ServeRun([i.executor for i in insts], res, s)
 
 
-def main():
+def main(argv: Optional[Sequence[str]] = None) -> ServeRun:
+    """Parse ``argv`` (default: the command line), build the engine(s),
+    serve a Poisson workload through the chosen scheduler and print the
+    summary. Returns what was served, so callers in the same process
+    (chip_smoke.py) can check it."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--scheduler", default="slice",
@@ -266,10 +310,12 @@ def main():
                          "MetricsSnapshot (pages in use, resident tasks, "
                          "defers, spec accept rate) every N loop cycles "
                          "(default 0 = off)")
-    ap.add_argument("--reduced", action="store_true", default=True,
-                    help="use the reduced (CPU-feasible) config")
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the reduced (CPU-feasible) config; "
+                         "--no-reduced serves the published widths")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     mesh_shape = None
     if args.mesh_shape is not None:
@@ -286,7 +332,6 @@ def main():
                              "via GSPMD; --paged-kernel needs a shard_map "
                              "wrapper (not implemented)")
         # must happen before the heavy imports below first-init jax
-        import os
         n = mesh_shape[0] * mesh_shape[1]
         if "xla_force_host_platform_device_count" not in os.environ.get(
                 "XLA_FLAGS", ""):
@@ -294,6 +339,7 @@ def main():
                 os.environ.get("XLA_FLAGS", "")
                 + f" --xla_force_host_platform_device_count={n}").strip()
 
+    configure_compile_cache()
     if args.fleet is not None:
         if args.executor != "paged":
             raise SystemExit("--fleet requires --executor paged (every "
@@ -450,6 +496,7 @@ def main():
           f"{swap_note}{spec_note}{pipe_note}")
     if tr is not None:
         _export_trace(tr, args, res.tasks, tr.events)
+    return ServeRun([ex], res, s)
 
 
 if __name__ == "__main__":

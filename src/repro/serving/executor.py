@@ -672,8 +672,14 @@ class PagedJaxExecutor(Executor):
         # the slot ring would silently wrap; we raise instead (decode()).
         self.jax, self.jnp, self.M = jax, jnp, M
         self.cfg = cfg
-        self.params = params if params is not None else M.init_params(
-            cfg, jax.random.PRNGKey(seed))
+        self.mesh = mesh
+        if mesh is None:
+            self.params = params if params is not None else M.init_params(
+                cfg, jax.random.PRNGKey(seed))
+            self.pages = M.init_paged_cache(cfg, n_pages, page_size)
+        else:
+            self._init_sharded(params, seed, n_pages, page_size,
+                               use_paged_kernel)
         self.page_size = page_size
         self.n_pages = n_pages
         self.max_seq = max_seq
@@ -694,7 +700,6 @@ class PagedJaxExecutor(Executor):
             self.prefix_cache = RadixPrefixCache(
                 self.pool, max_pages=prefix_cache_pages or n_pages)
         self.max_pages_per_seq = -(-max_seq // page_size)
-        self.pages = M.init_paged_cache(cfg, n_pages, page_size)
         # Cache-kind subsystem (DESIGN.md §12): SSM/hybrid archs add a
         # constant-size recurrent-state arena — slot-allocated by the
         # SSMStateStore exactly as the pool allocates pages — merged into
@@ -708,32 +713,6 @@ class PagedJaxExecutor(Executor):
             self.states = SSMStateStore(self.n_state_slots)
             self.pages.update(M.init_state_arena(cfg, self.n_state_slots))
         self.store = CacheStore(cfg, self.pool, self.states)
-        # Tensor-parallel mode (DESIGN.md §9): shard params/pages over the
-        # mesh BEFORE any step is lowered — AOT input shardings are taken
-        # from the example arrays, so the canonical layout must be pinned
-        # here once and preserved by every later update.
-        self.mesh = mesh
-        if mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-            from repro.launch import sharding as shard_rules
-            from repro.launch.mesh import batch_axes
-            if use_paged_kernel:
-                raise ValueError(
-                    "mesh mode shards the jnp paged-attention path via "
-                    "GSPMD; the Pallas kernel needs a shard_map wrapper "
-                    "(not implemented) — drop use_paged_kernel")
-            if "model" not in mesh.axis_names or "data" not in mesh.axis_names:
-                raise ValueError(
-                    "serving mesh needs ('data', 'model') axes — see "
-                    "launch.mesh.make_serving_mesh")
-            self._batch_axes = batch_axes(mesh)
-            self._repl_sh = NamedSharding(mesh, PartitionSpec())
-            self._page_sh = shard_rules.to_shardings(
-                mesh, shard_rules.page_specs(cfg, mesh))
-            self.params = jax.device_put(
-                self.params, shard_rules.to_shardings(
-                    mesh, shard_rules.param_specs(cfg, mesh, train=False)))
-            self.pages = jax.device_put(self.pages, self._page_sh)
         self._last_tok: Dict[int, int] = {}
         self._last_logits: Optional[np.ndarray] = None
         self._last_prefill_logits: Optional[np.ndarray] = None
@@ -812,6 +791,45 @@ class PagedJaxExecutor(Executor):
             self._build_verify_steps()
 
     # -- mesh plumbing (DESIGN.md §9) --
+    def _init_sharded(self, params, seed: int, n_pages: int, page_size: int,
+                      use_paged_kernel: bool) -> None:
+        """Tensor-parallel mode: place params and the page arena in their
+        canonical shardings BEFORE any step is lowered — AOT input
+        shardings are taken from the example arrays, so the layout pinned
+        here must be preserved by every later update. Fresh params and
+        pages are generated directly in their shardings (jit with
+        out_shardings): building the full arrays first would put the whole
+        model on one device, which for a model that needs the mesh is an
+        out-of-memory error before sharding ever happens."""
+        jax, M, cfg, mesh = self.jax, self.M, self.cfg, self.mesh
+        from jax.sharding import NamedSharding, PartitionSpec
+        from repro.launch import sharding as shard_rules
+        from repro.launch.mesh import batch_axes
+        if use_paged_kernel:
+            raise ValueError(
+                "mesh mode shards the jnp paged-attention path via "
+                "GSPMD; the Pallas kernel needs a shard_map wrapper "
+                "(not implemented) — drop use_paged_kernel")
+        if "model" not in mesh.axis_names or "data" not in mesh.axis_names:
+            raise ValueError(
+                "serving mesh needs ('data', 'model') axes — see "
+                "launch.mesh.make_serving_mesh")
+        self._batch_axes = batch_axes(mesh)
+        self._repl_sh = NamedSharding(mesh, PartitionSpec())
+        self._page_sh = shard_rules.to_shardings(
+            mesh, shard_rules.page_specs(cfg, mesh))
+        param_sh = shard_rules.to_shardings(
+            mesh, shard_rules.param_specs(cfg, mesh, train=False))
+        if params is None:
+            self.params = jax.jit(lambda k: M.init_params(cfg, k),
+                                  out_shardings=param_sh)(
+                jax.random.PRNGKey(seed))
+        else:
+            self.params = jax.device_put(params, param_sh)
+        self.pages = jax.jit(lambda: M.init_paged_cache(cfg, n_pages,
+                                                        page_size),
+                             out_shardings=self._page_sh)()
+
     def _dev_in(self, x):
         """Commit a step input as replicated over the mesh: AOT-compiled
         calls reject inputs whose shardings differ from the lowered

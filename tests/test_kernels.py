@@ -248,7 +248,10 @@ def test_moe_grouped_decode_matches_dense(skew, use_kernel):
     B, D, F, E, K = 7, 16, 32, 5, 2
     p = init_moe_params(KEY, D, F, E)
     p = p._replace(router=p.router.at[:, 0].add(skew * D ** -0.5))
-    x = jax.random.normal(jax.random.PRNGKey(3), (B, D))
+    # tokens share a positive mean, so the column-0 shift above raises
+    # expert 0's logit for every token (sum(x) > 0) instead of for an
+    # rng-dependent subset
+    x = jax.random.normal(jax.random.PRNGKey(3), (B, D)) + 1.0
     if skew:
         _, ids, _ = route(p.router, x, K)
         loads = np.bincount(np.asarray(ids).ravel(), minlength=E)
@@ -256,3 +259,16 @@ def test_moe_grouped_decode_matches_dense(skew, use_kernel):
     y_g, _ = moe_ffn_grouped_decode(p, x, K, use_kernel=use_kernel)
     y_d, _ = moe_ffn_dense(p, x, K)
     np.testing.assert_allclose(y_g, y_d, rtol=2e-5, atol=2e-5)
+
+
+def test_auto_interpret_refuses_other_backends(monkeypatch):
+    """interpret=None interprets on CPU, compiles on TPU and raises
+    elsewhere instead of quietly running the interpreter."""
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert ops._auto_interpret(None) is want
+        assert ops._auto_interpret(not want) is (not want)
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._auto_interpret(None)
+    assert ops._auto_interpret(True) is True

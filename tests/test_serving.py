@@ -154,7 +154,7 @@ def test_paged_executor_end_to_end():
 def test_jax_executor_compaction_matches_masked():
     """Bucketed compaction (gather->decode->scatter) must produce the same
     engine state evolution as masked full-array decode."""
-    from helpers import make_slot_engine, reduced_cfg
+    from helpers import assert_logits_close, make_slot_engine, reduced_cfg
     from repro.core.task import qa_task
 
     cfg = reduced_cfg()
@@ -169,6 +169,48 @@ def test_jax_executor_compaction_matches_masked():
         exA.decode([tasks[i] for i in subset])
         exB.decode([tasks[i] for i in subset])
     np.testing.assert_array_equal(exA.cache["length"], exB.cache["length"])
-    np.testing.assert_allclose(np.asarray(exA.cache["k"]),
-                               np.asarray(exB.cache["k"]), rtol=1e-5, atol=1e-6)
+    # the repo-wide engine contract (helpers.ATOL): compaction reorders
+    # the f32 reductions, so equality holds to 1e-5, not bit-for-bit
+    assert_logits_close(exA.cache["k"], exB.cache["k"])
     np.testing.assert_array_equal(exA.tokens, exB.tokens)
+
+
+def test_serve_main_returns_what_it_served(monkeypatch, tmp_path):
+    """serve.main(argv) runs the CLI path in-process and hands back its
+    engines and tasks (chip_smoke.py checks them the same way). With
+    JAX_COMPILATION_CACHE_DIR set it leaves the cache to JAX."""
+    from repro.launch import serve
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    # Orca: SLICE prices its cycles with l(b) probed on this host, and on
+    # a loaded host a noisy probe can leave every request unschedulable;
+    # this test is about what main returns, not about the policy
+    run = serve.main(["--executor", "paged", "--scheduler", "orca",
+                      "--slots", "2", "--max-seq", "64", "--rate", "2",
+                      "--duration", "1"])
+    assert run.result.tasks
+    assert all(t.finished for t in run.result.tasks)
+    assert run.summary["all"].n == len(run.result.tasks)
+    (ex,) = run.executors
+    assert ex.cfg.name.endswith("-smoke")        # --reduced is the default
+    ex.pool.check()
+    assert ex.pool.used_pages == 0
+
+
+def test_compile_cache_dir_follows_env(monkeypatch, tmp_path):
+    import jax
+
+    from repro.launch import serve
+
+    assert (serve.CHECKOUT / "src" / "repro" / "launch" / "serve.py").is_file()
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+        serve.configure_compile_cache(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        serve.configure_compile_cache(tmp_path)
+        assert (jax.config.jax_compilation_cache_dir
+                == str(tmp_path / ".jax_cache"))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -56,6 +56,35 @@ def test_page_arena_sharded_over_kv_heads(mesh4, shard_setup):
                                 exB.page_size, cfg.head_dim)
 
 
+def test_mesh_engine_inits_params_in_their_shardings(mesh4, shard_setup):
+    """An engine given no params generates them directly in their
+    shardings: every weight that the rules split over 'model' lands as
+    four quarter-size shards (no device ever holds the full array), and
+    the values are the single-device init for the same seed."""
+    import jax
+    from repro.launch import sharding as shard_rules
+
+    cfg, params = shard_setup                 # PRNGKey(0) == seed 0
+    exB = make_paged_engine(cfg, mesh=mesh4, seed=0)
+    specs = shard_rules.param_specs(cfg, mesh4, train=False)
+    got = jax.tree.leaves(exB.params)
+    want = jax.tree.leaves(params)
+    spec_leaves = jax.tree.leaves(
+        specs, is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
+    n_split = 0
+    for g, w, spec in zip(got, want, spec_leaves):
+        assert g.sharding.spec == spec
+        # jit fuses the scale multiply into the draw: equal to an ulp
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-6, atol=0)
+        if "model" in spec:
+            n_split += 1
+            assert all(s.data.size * 4 == g.size
+                       for s in g.addressable_shards)
+    assert n_split > 0
+    assert exB.pages["k_pages"].sharding.spec[2] == "model"
+
+
 def test_mesh_rejects_pallas_kernel(mesh4, shard_setup):
     cfg, params = shard_setup
     with pytest.raises(ValueError, match="shard_map"):
