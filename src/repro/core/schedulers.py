@@ -83,6 +83,24 @@ class Scheduler:
     # code only OBSERVES through these; it never branches on them.
     trace = None
     trace_name = "engine"
+    # True while the next next_action will replan (SliceScheduler); the
+    # profiler's slice/schedule span reads it
+    need_resched = False
+
+    def plan_args(self, now: float) -> dict:
+        """Arguments of the profiler's ``slice/schedule`` span, built only
+        while a profiler session runs."""
+        return {}
+
+    def note_drop(self, task: Task, reason: str, **detail) -> None:
+        """Drop a task (reason: deadline | pages) and count it in
+        ``drops_by_reason``. The reason and the decision's margin stay on
+        the task (``Task.drop_args``) for the serving loop, which releases
+        the task and traces the drop."""
+        task.dropped = True
+        task.drop_args = {"reason": reason, **detail}
+        d = self.drops_by_reason
+        d[reason] = d.get(reason, 0) + 1
 
     def note_defer(self, task: Task, now: float, reason: str) -> None:
         """Count one defer decision (reason: pages | states | time |
@@ -209,6 +227,7 @@ class SliceScheduler(Scheduler):
         self._arr_times: List[float] = []
         self._prefill_ewma: float = 0.0
         self.defers_by_reason: dict = {}    # observability (DESIGN.md §13)
+        self.drops_by_reason: dict = {}
         self.pool: List[Task] = []          # unscheduled, unfinished
         self.batch: List[Task] = []         # selected (sorted by rate desc)
         self.mask: Optional[np.ndarray] = None
@@ -325,29 +344,50 @@ class SliceScheduler(Scheduler):
                 return victims
         return []
 
+    def _rt_need(self, t: Task, now: float):
+        """(remaining, needed, prefill estimate) of a real-time task, in
+        ms: its remaining deadline budget, and what its remaining tokens
+        need at its full SLO rate, the not-yet-cached prompt tail
+        included."""
+        remaining_ms = t.slo.deadline_ms - (now - t.arrival_ms)
+        need_ms = (t.output_len - t.tokens_done) * t.slo.tpot_ms
+        prefill_ms = 0.0
+        if t.tokens_done == 0:
+            # chunked prefill / prefix cache: only the not-yet-cached
+            # prompt tail costs
+            cached = t.prefill_done_tokens
+            if self.prefix_hint is not None:
+                cached = max(cached, int(self.prefix_hint(t)))
+            prefill_ms = self.lat.prefill_ms(max(0, t.prompt_len - cached))
+            need_ms += prefill_ms
+        return remaining_ms, need_ms, prefill_ms
+
     def _drop_hopeless(self, now: float) -> None:
         """Deadline-feasibility pruning (beyond-paper): a real-time task whose
         remaining tokens cannot fit in its remaining deadline budget — even at
         its full SLO rate — is already a violation; dropping it immediately
-        frees cycle capacity for still-feasible tasks."""
+        frees cycle capacity for still-feasible tasks. Each drop keeps its
+        (negative) slack, the tokens done and the prefill estimate."""
         if not self.drop_expired_realtime:
             return
         for t in list(self.batch) + self.pool:
-            if not t.slo.realtime or t.finished:
+            if not t.slo.realtime or t.finished or t.dropped:
                 continue
-            remaining_ms = t.slo.deadline_ms - (now - t.arrival_ms)
-            need_ms = (t.output_len - t.tokens_done) * t.slo.tpot_ms
-            if t.tokens_done == 0:
-                # chunked prefill / prefix cache: only the not-yet-cached
-                # prompt tail costs
-                cached = t.prefill_done_tokens
-                if self.prefix_hint is not None:
-                    cached = max(cached, int(self.prefix_hint(t)))
-                need_ms += self.lat.prefill_ms(
-                    max(0, t.prompt_len - cached))
+            remaining_ms, need_ms, prefill_ms = self._rt_need(t, now)
             if need_ms > remaining_ms:
-                t.dropped = True
+                self.note_drop(t, "deadline",
+                               slack_ms=remaining_ms - need_ms,
+                               tokens_done=t.tokens_done,
+                               prefill_ms=prefill_ms)
         self.pool = [t for t in self.pool if not t.dropped]
+
+    def plan_args(self, now: float) -> dict:
+        """The least deadline slack among the real-time tasks still held
+        (``rt_slack_min_ms``); a dropped task's own is on its drop."""
+        slack = [r - n for r, n, _ in (
+            self._rt_need(t, now) for t in self.batch + self.pool
+            if t.slo.realtime and not t.finished and not t.dropped)]
+        return {"rt_slack_min_ms": min(slack)} if slack else {}
 
     def _reschedule(self, now: float) -> None:
         # fold still-running unfinished tasks back into the pool (Alg. 1
@@ -363,7 +403,10 @@ class SliceScheduler(Scheduler):
             # or whole pool) would be deferred forever — drop it visibly
             for t in candidates:
                 if not t.dropped and self.page_budget.infeasible(t):
-                    t.dropped = True
+                    self.note_drop(
+                        t, "pages",
+                        peak_tokens=self.page_budget.peak_tokens(t),
+                        pages=self.page_budget.pages_for(t))
         candidates = [t for t in candidates if not t.dropped]
         sel_budget = (self.budget_ms - self._headroom_ms()
                       - self._swap_headroom_ms(candidates))

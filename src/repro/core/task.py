@@ -70,6 +70,9 @@ class Task:
     prefill_done_tokens: int = 0       # prompt tokens cached (chunked prefill)
     token_times_ms: list = dataclasses.field(default_factory=list)
     dropped: bool = False
+    # why the scheduler dropped it (Scheduler.note_drop): the reason and
+    # the decision's margin, for the serving loop's drop span and row
+    drop_args: Optional[dict] = None
     # Cache swapped to host (DESIGN.md §7, §12): logical length preserved,
     # device residency released — KV pages for attention archs, the
     # constant-size recurrent-state slot for SSM/hybrid archs, both for

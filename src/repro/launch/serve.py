@@ -62,12 +62,11 @@ says; when that is unset, at ``<checkout>/.jax_cache``.
 
   # observability (DESIGN.md §13): record the per-request lifecycle
   # stream and export a Perfetto/Chrome-trace timeline (one track per
-  # instance, flow arrows per request); --metrics-every also samples
-  # the counters/gauges snapshot every N loop cycles. Composes with
-  # every flag above, including --async-pipeline (spans are recorded at
-  # commit time, so timestamps stay causal under dispatch-ahead)
+  # instance, flow arrows per request). Composes with every flag above,
+  # including --async-pipeline (spans are recorded at commit time, so
+  # timestamps stay causal under dispatch-ahead)
   PYTHONPATH=src python -m repro.launch.serve --executor paged \
-      --trace out.json --metrics-every 32
+      --trace out.json
 """
 from __future__ import annotations
 
@@ -108,13 +107,12 @@ def _make_trace(args):
     if args.trace is None:
         return None
     from repro.serving.trace import TraceRecorder
-    return TraceRecorder(capacity=1 << 20,
-                         metrics_every=args.metrics_every)
+    return TraceRecorder(capacity=1 << 20)
 
 
 def _export_trace(tr, args, tasks, events) -> None:
     """Write the Perfetto JSON + print the observability summary line
-    (events, snapshots, SLO-violation attribution buckets)."""
+    (events, SLO-violation attribution buckets)."""
     if tr is None:
         return
     from repro.serving.metrics import slo_attribution
@@ -122,7 +120,7 @@ def _export_trace(tr, args, tasks, events) -> None:
     att = slo_attribution(tasks, events)
     buckets = {k: v for k, v in att["buckets"].items() if v}
     print(f"trace: {len(tr)} events ({tr.dropped} dropped) "
-          f"{len(tr.snapshots)} snapshots -> {args.trace} ({rows} rows); "
+          f"-> {args.trace} ({rows} rows); "
           f"violations={att['violations']} attribution={buckets or '{}'}")
 
 
@@ -215,7 +213,8 @@ def _run_fleet(args):
     print(f"fleet({','.join(archs)}): n={s['all'].n} SLO={s['all'].slo:.1%} "
           f"RT={s['realtime'].slo:.1%} nRT={s['non_realtime'].slo:.1%} "
           f"spills={res.spills} degraded={res.degraded} "
-          f"defers={dict(res.merged.defers_by_reason)}")
+          f"defers={dict(res.merged.defers_by_reason)} "
+          f"drops={dict(res.merged.drops_by_reason)}")
     for name, a in per_tier(res.tasks).items():
         print(f"  {name}: served={a.n} "
               f"admitted={res.admissions.get(name, 0)} SLO={a.slo:.1%}")
@@ -300,16 +299,15 @@ def main(argv: Optional[Sequence[str]] = None) -> ServeRun:
                          "count is forced via XLA_FLAGS automatically")
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="record the per-request lifecycle stream "
-                         "(DESIGN.md §13) and write a Perfetto/Chrome-"
-                         "trace JSON timeline here — open in "
-                         "ui.perfetto.dev or chrome://tracing. Composes "
+                         "(DESIGN.md §13) on the loop clock and write a "
+                         "Perfetto/Chrome-trace JSON timeline here — open "
+                         "in ui.perfetto.dev or chrome://tracing. Its rows "
+                         "come from the same span call that, while a "
+                         "jax.profiler session runs, also marks the "
+                         "program's phases (slice/step, slice/decode/"
+                         "sync, ...) on the profiler's clock. Composes "
                          "with every mode incl. --fleet and "
                          "--async-pipeline (commit-time spans)")
-    ap.add_argument("--metrics-every", type=int, default=0, metavar="N",
-                    help="with --trace: also sample a counters/gauges "
-                         "MetricsSnapshot (pages in use, resident tasks, "
-                         "defers, spec accept rate) every N loop cycles "
-                         "(default 0 = off)")
     ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
                     default=True,
                     help="serve the reduced (CPU-feasible) config; "
@@ -492,7 +490,8 @@ def main(argv: Optional[Sequence[str]] = None) -> ServeRun:
           f"RT={s['realtime'].slo:.1%} nRT={s['non_realtime'].slo:.1%} "
           f"decode_iters={res.decode_iterations} "
           f"prefill_chunks={res.prefill_chunks} "
-          f"defers={dict(res.defers_by_reason)}"
+          f"defers={dict(res.defers_by_reason)} "
+          f"drops={dict(res.drops_by_reason)}"
           f"{swap_note}{spec_note}{pipe_note}")
     if tr is not None:
         _export_trace(tr, args, res.tasks, tr.events)

@@ -70,6 +70,7 @@ from repro.serving.state_store import (CacheStore, OutOfStates,
                                        SSMStateStore)
 from repro.serving.pipeline import (DispatchQueue, GapStats, PendingStep,
                                     TransferLedger)
+from repro.serving.trace import span
 
 
 _PREFILL_PRIOR = [(64, 10.0), (512, 40.0)]   # prefill ms prior until measured
@@ -177,13 +178,6 @@ class Executor:
 
     def latency_model(self) -> LatencyModel:
         raise NotImplementedError
-
-    def trace_gauges(self) -> Dict[str, int]:
-        """Observability gauge surface (DESIGN.md §13): point-in-time
-        resource occupancy for MetricsSnapshot sampling. Read-only —
-        never mutates executor state. Executors without a paged arena
-        report nothing."""
-        return {}
 
 
 class SimExecutor(Executor):
@@ -305,10 +299,6 @@ class PagedSimExecutor(SimExecutor):
         """Pages currently pinned — the sim-side analogue of
         PagePool.used_pages, so fleet leak checks read uniformly."""
         return sum(self.held.values())
-
-    def trace_gauges(self) -> Dict[str, int]:
-        return {"pages_in_use": self.used_pages,
-                "pages_total": self.budget.total_pages}
 
     def prefill(self, task: Task) -> float:
         self.held[task.task_id] = self.budget.pages_for(task)
@@ -1296,49 +1286,59 @@ class PagedJaxExecutor(Executor):
         logits = None
         t_all = time.perf_counter()
         for c in _chunk_pieces(n, self.prefill_chunk_size):
-            # incremental allocation: an OutOfPages here propagates with the
-            # pool and progress consistent (progress is advanced per PIECE,
-            # below), so a deferred task resumes from its cached tokens
-            if self.pool.holds(tid):
-                self._reserve(lambda e=done + c: self.pool.extend(tid, e))
-            else:
-                self._reserve(lambda e=c: self.pool.alloc(tid, e))
-            self._ensure_range_writable(tid, done, done + c)
-            row = self.pool.page_table(tid)
-            pt = np.full((1, self.max_pages_per_seq), -1, np.int32)
-            pt[0, : len(row)] = row
-            piece = self._dev_in(jnp.asarray(toks_full[:, done:done + c],
-                                             jnp.int32))
-            t0 = time.perf_counter()
-            logits, self.pages = self._chunk_jit[c](
-                self.params, self.pages, self._dev_in(pt),
-                self._dev_in(jnp.asarray([done], jnp.int32)), piece)
-            if not async_on:
-                logits.block_until_ready()
-                ms += (time.perf_counter() - t0) * 1000.0
-            done += c
-            self._chunk_progress[tid] = done
-            self._insert_prefix(task, toks_full, upto=done)
+            # one compiled step per piece: its bucket is the piece's size
+            with span("prefill_chunk/piece", lambda: {
+                    "task": tid, "start": done, "n": c, "bucket": c}):
+                with span("prefill_chunk/piece/prep"):
+                    # incremental allocation: an OutOfPages here propagates
+                    # with the pool and progress consistent (progress is
+                    # advanced per PIECE, below), so a deferred task
+                    # resumes from its cached tokens
+                    if self.pool.holds(tid):
+                        self._reserve(
+                            lambda e=done + c: self.pool.extend(tid, e))
+                    else:
+                        self._reserve(lambda e=c: self.pool.alloc(tid, e))
+                    self._ensure_range_writable(tid, done, done + c)
+                    row = self.pool.page_table(tid)
+                    pt = np.full((1, self.max_pages_per_seq), -1, np.int32)
+                    pt[0, : len(row)] = row
+                    piece = self._dev_in(jnp.asarray(
+                        toks_full[:, done:done + c], jnp.int32))
+                t0 = time.perf_counter()
+                with span("prefill_chunk/piece/launch"):
+                    logits, self.pages = self._chunk_jit[c](
+                        self.params, self.pages, self._dev_in(pt),
+                        self._dev_in(jnp.asarray([done], jnp.int32)), piece)
+                if not async_on:
+                    with span("prefill_chunk/piece/sync"):
+                        logits.block_until_ready()
+                    ms += (time.perf_counter() - t0) * 1000.0
+                done += c
+                self._chunk_progress[tid] = done
+                self._insert_prefix(task, toks_full, upto=done)
         if done >= L:
             if logits is None:       # fully cached via acquire: the final
                 # block is capped at L-1, so at least one token always
                 # remains to compute — logits cannot be None here
                 raise RuntimeError(f"task {tid}: empty final chunk")
-            if async_on:
-                entry = (self._argmax_jit(logits), 0)
-                self._tok_dev[tid] = entry
-                waited = self._push(PendingStep(
-                    "prefill", [tid],
-                    {"tid": tid, "logits": logits, "tok_dev": {tid: entry},
-                     "fresh": fresh, "pre_len": pre_len,
-                     "pre_progress": pre_progress}))
-                ms = max(0.0, (time.perf_counter() - t_all) * 1000.0 - waited)
-                self.gap_stats.dispatch_ms += ms
-            else:
-                self._last_prefill_logits_src = None
-                self._last_prefill_logits = np.asarray(logits)
-                self._set_first_token(tid, int(jnp.argmax(logits[0])))
-                self.gap_stats.wait_ms += ms
+            with span("prefill_chunk/first_token"):
+                if async_on:
+                    entry = (self._argmax_jit(logits), 0)
+                    self._tok_dev[tid] = entry
+                    waited = self._push(PendingStep(
+                        "prefill", [tid],
+                        {"tid": tid, "logits": logits,
+                         "tok_dev": {tid: entry}, "fresh": fresh,
+                         "pre_len": pre_len, "pre_progress": pre_progress}))
+                    ms = max(0.0, (time.perf_counter() - t_all) * 1000.0
+                             - waited)
+                    self.gap_stats.dispatch_ms += ms
+                else:
+                    self._last_prefill_logits_src = None
+                    self._last_prefill_logits = np.asarray(logits)
+                    self._set_first_token(tid, int(jnp.argmax(logits[0])))
+                    self.gap_stats.wait_ms += ms
             return ms, True
         if async_on:
             ms = (time.perf_counter() - t_all) * 1000.0
@@ -1399,16 +1399,13 @@ class PagedJaxExecutor(Executor):
             held_states=lambda t: self.states.resident_slot_count(t.task_id),
             **kw)
 
-    def trace_gauges(self) -> Dict[str, int]:
-        g = {"pages_in_use": self.pool.used_pages,
-             "pages_total": self.n_pages}
-        if self.states is not None:
-            g["states_in_use"] = self.states.used_slots
-            g["states_total"] = self.n_state_slots
-        return g
-
     # -- ops --
     def prefill(self, task: Task) -> float:
+        with span("prefill", lambda: {"task": task.task_id,
+                                      "n": self._effective_prompt(task)}):
+            return self._prefill(task)
+
+    def _prefill(self, task: Task) -> float:
         jax, jnp, M = self.jax, self.jnp, self.M
         tid = task.task_id
         L = self._effective_prompt(task)
@@ -1580,7 +1577,6 @@ class PagedJaxExecutor(Executor):
 
     def decode(self, tasks: Sequence[Task],
                depths: Optional[Sequence[int]] = None) -> float:
-        jnp = self.jnp
         if len(tasks) > self.max_batch:
             raise RuntimeError(f"decode batch {len(tasks)} > max_batch "
                                f"{self.max_batch}")
@@ -1588,96 +1584,118 @@ class PagedJaxExecutor(Executor):
             if self.draft is None:
                 raise RuntimeError("executor built without spec_decode=True")
             return self._decode_spec(tasks, [int(d) for d in depths])
+        with span("decode") as sp:
+            return self._decode(tasks, sp)
+
+    def _decode(self, tasks: Sequence[Task], sp) -> float:
+        """One plain decode step, in the phases the profiler's spans name:
+        ``prep`` (page reservation, CoW, page table and inputs),
+        ``launch`` (the compiled step's call), ``sync`` (the wait on its
+        results, or under async dispatch on an older step's commit) and
+        ``readback`` (the token read)."""
+        jnp = self.jnp
         ids = [t.task_id for t in tasks]
         t_disp = time.perf_counter()
-        lengths = [self.pool.length(i) for i in ids]
-        for i, ln in zip(ids, lengths):
-            if ln + 1 > self.max_seq:
-                raise RuntimeError(f"task {i} exceeds max_seq {self.max_seq}")
-            self._reserve(
-                lambda i=i, ln=ln: self.pool.extend(i, ln + 1))
-            self._ensure_range_writable(i, ln, ln + 1)   # CoW (DESIGN.md §6)
-        b = 1
-        while b < len(tasks):
-            b *= 2
-        b = min(b, self.max_batch)
-        maxp = self.max_pages_per_seq
-        pt = np.full((b, maxp), -1, np.int32)
-        for r, i in enumerate(ids):
-            row = self.pool.page_table(i)
-            pt[r, : len(row)] = row
-        ln = np.zeros((b,), np.int32)
-        ln[: len(ids)] = lengths
-        av = np.zeros((b,), bool)
-        av[: len(ids)] = True
-        sl = None
-        if self.states is not None:
-            # per-row recurrent-state slots; pad rows carry -1 (the step's
-            # write mask drops them, the clipped read is inert)
-            sl = np.full((b,), -1, np.int32)
-            sl[: len(ids)] = [self.states.slot_of(i) for i in ids]
-        if self._async_on():
+        async_on = self._async_on()
+        with span("decode/prep"):
+            lengths = [self.pool.length(i) for i in ids]
+            for i, ln in zip(ids, lengths):
+                if ln + 1 > self.max_seq:
+                    raise RuntimeError(
+                        f"task {i} exceeds max_seq {self.max_seq}")
+                self._reserve(
+                    lambda i=i, ln=ln: self.pool.extend(i, ln + 1))
+                self._ensure_range_writable(i, ln, ln + 1)  # CoW (§6)
+            b = 1
+            while b < len(tasks):
+                b *= 2
+            b = min(b, self.max_batch)
+            maxp = self.max_pages_per_seq
+            pt = np.full((b, maxp), -1, np.int32)
+            for r, i in enumerate(ids):
+                row = self.pool.page_table(i)
+                pt[r, : len(row)] = row
+            ln = np.zeros((b,), np.int32)
+            ln[: len(ids)] = lengths
+            av = np.zeros((b,), bool)
+            av[: len(ids)] = True
+            sl = None
+            if self.states is not None:
+                # per-row recurrent-state slots; pad rows carry -1 (the
+                # step's write mask drops them, the clipped read is inert)
+                sl = np.full((b,), -1, np.int32)
+                sl[: len(ids)] = [self.states.slot_of(i) for i in ids]
+            if not async_on:
+                tk = np.zeros((b,), np.int32)
+                tk[: len(ids)] = [self._last_tok[i] for i in ids]
+        sp.set(lambda: {"b": b, "rows": len(ids),
+                        "ctx_max": max(lengths) + 1})
+        if async_on:
             # dispatch-ahead: the input token vector chains on-device off
             # the in-flight argmax — no host round-trip — and the step's
             # observation rides the queue until commit time. Plain decode
             # always commits exactly one token per task (control flow is
             # length-based), so host accounting can proceed optimistically
             # at dispatch.
-            tk_dev = self._chain_vector(ids, b)
-            if tk_dev is None:
-                if any(i in self._tok_dev for i in ids):
-                    tk_dev = jnp.stack(
-                        [self._chain_tok(i) for i in ids]
-                        + [np.int32(0)] * (b - len(ids)))
-                else:            # fully committed: plain host vector
-                    tk_np = np.zeros((b,), np.int32)
-                    tk_np[: len(ids)] = [self._last_tok[i] for i in ids]
-                    tk_dev = tk_np
-            key = (tuple(ids), b)
-            extra = (() if sl is None
-                     else (self._cached_in("sl", key, sl),))
-            logits, am, ln_next, self.pages = self._step_jit[b](
-                self.params, self.pages,
-                self._cached_in("pt", key, pt),
-                self._cached_in("ln", key, ln),
-                self._dev_in(tk_dev),
-                self._cached_in("av", key, av), *extra)
-            # chain next cycle's lengths off the fused output: every
-            # active row grew by exactly one token, which is also what
-            # pool.length will report when the next decode builds ln
-            self._in_cache["ln"] = (key, (ln + av).astype(np.int32), ln_next)
-            tok_dev = {}
-            for r, i in enumerate(ids):
-                tok_dev[i] = self._tok_dev[i] = (am, r)
-            self._last_am = (tuple(ids), b, am)
-            waited = self._push(PendingStep(
-                "decode", ids,
-                {"logits": logits, "argmax": am, "tok_dev": tok_dev,
-                 "pre_lengths": dict(zip(ids, lengths))}))
+            with span("decode/launch"):
+                tk_dev = self._chain_vector(ids, b)
+                if tk_dev is None:
+                    if any(i in self._tok_dev for i in ids):
+                        tk_dev = jnp.stack(
+                            [self._chain_tok(i) for i in ids]
+                            + [np.int32(0)] * (b - len(ids)))
+                    else:            # fully committed: plain host vector
+                        tk_np = np.zeros((b,), np.int32)
+                        tk_np[: len(ids)] = [self._last_tok[i] for i in ids]
+                        tk_dev = tk_np
+                key = (tuple(ids), b)
+                extra = (() if sl is None
+                         else (self._cached_in("sl", key, sl),))
+                logits, am, ln_next, self.pages = self._step_jit[b](
+                    self.params, self.pages,
+                    self._cached_in("pt", key, pt),
+                    self._cached_in("ln", key, ln),
+                    self._dev_in(tk_dev),
+                    self._cached_in("av", key, av), *extra)
+                # chain next cycle's lengths off the fused output: every
+                # active row grew by exactly one token, which is also what
+                # pool.length will report when the next decode builds ln
+                self._in_cache["ln"] = (key, (ln + av).astype(np.int32),
+                                        ln_next)
+                tok_dev = {}
+                for r, i in enumerate(ids):
+                    tok_dev[i] = self._tok_dev[i] = (am, r)
+                self._last_am = (tuple(ids), b, am)
+            with span("decode/sync"):
+                waited = self._push(PendingStep(
+                    "decode", ids,
+                    {"logits": logits, "argmax": am, "tok_dev": tok_dev,
+                     "pre_lengths": dict(zip(ids, lengths))}))
             ms = max(0.0, (time.perf_counter() - t_disp) * 1000.0 - waited)
             self.gap_stats.dispatch_ms += ms
             return ms
-        tk = np.zeros((b,), np.int32)
-        tk[: len(ids)] = [self._last_tok[i] for i in ids]
         t0 = time.perf_counter()
-        extra = () if sl is None else (self._dev_in(sl),)
-        logits, am, _, self.pages = self._step_jit[b](
-            self.params, self.pages, self._dev_in(pt), self._dev_in(ln),
-            self._dev_in(tk), self._dev_in(av), *extra)
-        am.block_until_ready()
+        with span("decode/launch"):
+            extra = () if sl is None else (self._dev_in(sl),)
+            logits, am, _, self.pages = self._step_jit[b](
+                self.params, self.pages, self._dev_in(pt), self._dev_in(ln),
+                self._dev_in(tk), self._dev_in(av), *extra)
+        with span("decode/sync"):
+            am.block_until_ready()
         ms = (time.perf_counter() - t0) * 1000.0
-        # logits stay device-resident until someone reads last_logits —
-        # the sync path shares the async commit's lazy materialization
-        self._last_logits = None
-        self._last_logits_src = (logits, len(ids))
-        new_toks = np.asarray(am)[: len(ids)]
-        for i, tok in zip(ids, new_toks):
-            self._last_tok[i] = int(tok)
-            self._tok_dev.pop(i, None)
-            # setdefault: latency-model probes decode without a real
-            # prefill, so they have no first-token history entry
-            self._gen.setdefault(i, []).append(int(tok))
-        self._last_commits = [1] * len(ids)
+        with span("decode/readback"):
+            # logits stay device-resident until someone reads last_logits —
+            # the sync path shares the async commit's lazy materialization
+            self._last_logits = None
+            self._last_logits_src = (logits, len(ids))
+            new_toks = np.asarray(am)[: len(ids)]
+            for i, tok in zip(ids, new_toks):
+                self._last_tok[i] = int(tok)
+                self._tok_dev.pop(i, None)
+                # setdefault: latency-model probes decode without a real
+                # prefill, so they have no first-token history entry
+                self._gen.setdefault(i, []).append(int(tok))
+            self._last_commits = [1] * len(ids)
         self.gap_stats.wait_ms += ms
         self.gap_stats.cycles += 1
         return ms

@@ -50,6 +50,11 @@ from repro.core.task import Task
 from repro.serving.executor import Executor
 from repro.serving.kv_pool import OutOfPages
 from repro.serving.kv_swap import HostArenaFull
+from repro.serving.trace import SPAN_KINDS, action_span, span
+
+# an action's kind, on both trace sinks (SPAN_KINDS order)
+_KIND = dict(zip((PrefillAction, PrefillChunkAction, DecodeAction,
+                  SuspendAction, ResumeAction), SPAN_KINDS))
 
 
 @dataclasses.dataclass
@@ -87,6 +92,8 @@ class LoopResult:
     # every replan whether or not a TraceRecorder is attached — the fleet
     # layer folds degraded down-tier routings in as "tier".
     defers_by_reason: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # drop decisions by cause (deadline | pages), counted the same way
+    drops_by_reason: Dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 class InstanceDriver:
@@ -106,13 +113,13 @@ class InstanceDriver:
         self.executor = executor
         # observability (DESIGN.md §13): an optional TraceRecorder shared
         # by every layer of this instance. The driver wires it into the
-        # scheduler (defer/admit/spec_grant emission) and stamps every
+        # scheduler (defer/admit/spec_grant/drop emission) and stamps every
         # event on the LOOP clock — under async dispatch, spans are
         # emitted AFTER fold_wait() so their timestamps are commit-time
         # and stay causal. trace=None is the zero-overhead default.
         self.trace = trace
         self.name = name
-        self.steps = 0
+        self.steps = 0                 # actions run: the slice/step index
         if trace is not None:
             scheduler.trace = trace
             scheduler.trace_name = name
@@ -162,12 +169,18 @@ class InstanceDriver:
         # dropped tasks never reach the finish path below, so their KV
         # (slots or pages) must be reclaimed here or it leaks for the rest
         # of the run — and memory-aware admission would over-promise.
+        # each drop is traced once, in both sinks, with the scheduler's
+        # reason and margin (Scheduler.note_drop)
         still = []
         for t in self.tracked:
             if t.dropped:
                 self.executor.release(t)
+                args = t.drop_args or {}
+                with span("drop", lambda: {"task": t.task_id, **args}):
+                    pass
                 if self.trace is not None:
-                    self.trace.emit("drop", self.now, t.task_id, self.name)
+                    self.trace.emit("drop", self.now, t.task_id, self.name,
+                                    **args)
             elif not t.finished:
                 still.append(t)
         self.tracked[:] = still
@@ -180,19 +193,26 @@ class InstanceDriver:
         """Run one scheduler action; False when the scheduler is idle
         (nothing executed, clock untouched — the caller decides whether
         to jump to the next arrival, spill work in, or stop)."""
+        with action_span(self.trace, self.name, self.stats,
+                         self.steps) as act:
+            return self._step(act)
+
+    def _step(self, act) -> bool:
         scheduler, executor = self.scheduler, self.executor
         tr = self.trace
-        g0 = (self.stats.as_dict()
-              if (tr is not None and self.stats is not None) else None)
-        t_sched = time.perf_counter()
-        action = scheduler.next_action(self.now)  # may drop (reschedule)
-        if self.stats is not None:
-            self.stats.schedule_ms += (time.perf_counter() - t_sched) * 1000.0
+        with span("schedule", lambda: {
+                "replanned": scheduler.need_resched}) as sp:
+            t_sched = time.perf_counter()
+            action = scheduler.next_action(self.now)  # may drop (reschedule)
+            if self.stats is not None:
+                self.stats.schedule_ms += (
+                    (time.perf_counter() - t_sched) * 1000.0)
+            sp.set(lambda: scheduler.plan_args(self.now))
         self.release_dropped()
         if action is None:
             return False
         t0 = self.now
-        ev = None  # (kind, task_id, args) when tracing; span emitted at end
+        tid, args = -1, None  # the recorder row's task and payload
         if isinstance(action, PrefillAction):
             t = action.task
             ms = executor.prefill(t)
@@ -202,7 +222,7 @@ class InstanceDriver:
             t.token_times_ms.append(self.now)  # first token at prefill end
             self.n_prefill += 1
             if tr is not None:
-                ev = ("prefill", t.task_id, {"tokens": t.prompt_len})
+                tid, args = t.task_id, {"tokens": t.prompt_len}
             if hasattr(scheduler, "note_prefilled"):
                 scheduler.note_prefilled(t)
             if t.finished:
@@ -222,8 +242,8 @@ class InstanceDriver:
                 t.prefill_done_tokens = max(t.prefill_done_tokens,
                                             min(t.prompt_len, int(prog(t))))
             if tr is not None:
-                ev = ("prefill_chunk", t.task_id,
-                      {"n": action.n_tokens, "done": bool(done)})
+                tid, args = t.task_id, {"n": action.n_tokens,
+                                        "done": bool(done)}
             if done:
                 # first token at FINAL chunk completion (TTFT convention)
                 t.prefill_done_tokens = t.prompt_len
@@ -249,13 +269,13 @@ class InstanceDriver:
                 else:
                     raise
                 if tr is not None:
-                    ev = ("suspend", t.task_id, {"ok": False})
+                    tid, args = t.task_id, {"ok": False}
             else:
                 self.now += ms
                 t.suspended = True
                 self.n_suspend += 1
                 if tr is not None:
-                    ev = ("suspend", t.task_id, {"ok": True})
+                    tid, args = t.task_id, {"ok": True}
         elif isinstance(action, ResumeAction):
             t = action.task
             try:
@@ -268,13 +288,13 @@ class InstanceDriver:
                 else:
                     raise
                 if tr is not None:
-                    ev = ("resume", t.task_id, {"ok": False})
+                    tid, args = t.task_id, {"ok": False}
             else:
                 self.now += ms
                 t.suspended = False
                 self.n_resume += 1
                 if tr is not None:
-                    ev = ("resume", t.task_id, {"ok": True})
+                    tid, args = t.task_id, {"ok": True}
         elif isinstance(action, DecodeAction):
             if action.depths is not None:
                 # speculative iteration (DESIGN.md §8): the executor
@@ -297,10 +317,9 @@ class InstanceDriver:
                     if t.finished:
                         self._finish(t)
                 if tr is not None:
-                    ev = ("decode", -1,
-                          {"n": len(action.tasks),
-                           "depth": max(action.depths),
-                           "spec_extra": self.n_spec_extra - pre_extra})
+                    args = {"n": len(action.tasks),
+                            "depth": max(action.depths),
+                            "spec_extra": self.n_spec_extra - pre_extra}
             else:
                 ms = executor.decode(action.tasks)
                 self.now += ms
@@ -310,30 +329,14 @@ class InstanceDriver:
                     if t.finished:
                         self._finish(t)
                 if tr is not None:
-                    ev = ("decode", -1,
-                          {"n": len(action.tasks), "depth": 0,
-                           "spec_extra": 0})
+                    args = {"n": len(action.tasks), "depth": 0,
+                            "spec_extra": 0}
         self.fold_wait()
-        if tr is not None:
-            if ev is not None:
-                kind, tid, args = ev
-                if g0 is not None:
-                    # host/device gap deltas measured across this action
-                    # (schedule time included — g0 precedes next_action)
-                    end = self.stats.as_dict()
-                    for k in ("schedule_ms", "dispatch_ms", "wait_ms",
-                              "swap_overlap_ms"):
-                        args[k] = end[k] - g0[k]
-                # span starts at the pre-action clock; under async dispatch
-                # the folded commit wait is inside dur, so spans on one
-                # track stay monotonic and non-overlapping
-                tr.push(kind, t0, tid, self.name, self.now - t0, args)
-            self.steps += 1
-            if tr.metrics_every and self.steps % tr.metrics_every == 0:
-                tr.sample(self.now, self.name, executor=self.executor,
-                          scheduler=self.scheduler,
-                          resident=len(self.live_tasks()),
-                          suspends=self.n_suspend, resumes=self.n_resume)
+        # the span starts at the pre-action clock; under async dispatch the
+        # folded commit wait is inside dur, so spans on one track stay
+        # monotonic and non-overlapping
+        act.record(_KIND[type(action)], tid, t0, self.now - t0, args)
+        self.steps += 1
         return True
 
     def drain(self) -> None:
@@ -373,6 +376,9 @@ class InstanceDriver:
                           pipeline_stalls=stalls,
                           defers_by_reason=dict(
                               getattr(self.scheduler, "defers_by_reason",
+                                      None) or {}),
+                          drops_by_reason=dict(
+                              getattr(self.scheduler, "drops_by_reason",
                                       None) or {}))
 
 
@@ -387,9 +393,12 @@ def merge_results(per_instance: Dict[str, LoopResult]) -> LoopResult:
         return LoopResult(tasks=[], end_ms=0.0, decode_iterations=0,
                           prefills=0)
     defers: Dict[str, int] = {}
+    drops: Dict[str, int] = {}
     for r in results:
         for k, v in r.defers_by_reason.items():
             defers[k] = defers.get(k, 0) + v
+        for k, v in r.drops_by_reason.items():
+            drops[k] = drops.get(k, 0) + v
     return LoopResult(
         tasks=[t for r in results for t in r.tasks],
         end_ms=max(r.end_ms for r in results),
@@ -407,7 +416,7 @@ def merge_results(per_instance: Dict[str, LoopResult]) -> LoopResult:
         wait_ms=sum(r.wait_ms for r in results),
         swap_overlap_ms=sum(r.swap_overlap_ms for r in results),
         pipeline_stalls=sum(r.pipeline_stalls for r in results),
-        defers_by_reason=defers)
+        defers_by_reason=defers, drops_by_reason=drops)
 
 
 def run_serving_loop(scheduler: Scheduler, executor: Executor,

@@ -1,5 +1,14 @@
 """Serving observability spine (DESIGN.md §13): per-request lifecycle
-tracing with near-zero overhead when disabled.
+tracing and program spans, with near-zero overhead when disabled.
+
+One span call, two sinks. ``span(name, args)`` opens a
+``jax.profiler.TraceAnnotation("slice/<name>")`` while a profiler session
+runs, so the program's host phases land on the profiler's own host clock,
+the clock the device's operations are aligned to; with no session it
+returns one shared no-op context and never calls ``args``.
+``action_span`` is the serving loop's per-action scope: the profiler's
+``slice/step`` span, and the ``TraceRecorder`` row of the action when a
+recorder is attached. Both sinks name an action by ``SPAN_KINDS``.
 
 ``TraceRecorder`` is a ring buffer of structured ``TraceEvent`` rows
 stamped on the LOOP clock (``InstanceDriver.now`` — simulated ms for the
@@ -10,8 +19,11 @@ loop folded the deferred device wait into ``now``, so timestamps stay
 causal whatever the dispatch mode.
 
 The overhead contract:
-  * disabled  — tracing off is ``trace=None``; every emission site is a
-    single ``is not None`` test, no event objects, no clock reads;
+  * disabled  — tracing off is ``trace=None`` and no profiler session;
+    every emission site is a single ``is not None`` test or one
+    ``TraceMe.is_enabled()`` check, no event objects, no clock reads, no
+    span arguments built. Spans wrap existing code only: they add no
+    device operation, sync or transfer (tests/test_trace.py);
   * enabled   — events are READ-ONLY observations of decisions already
     taken. Policy code never branches on the recorder, so token streams
     and every benchmark-gate metric are byte-identical traced vs.
@@ -21,8 +33,8 @@ The overhead contract:
 Event kinds (the lifecycle stream of DESIGN.md §13):
 
   instant   arrive / admit / defer(reason=pages|states|time|batch|tier) /
-            route(tier, score, degraded) / spec_grant(depth) / drop /
-            finish(tier, ok)
+            route(tier, score, degraded) / spec_grant(depth) /
+            drop(reason=deadline|pages, slack_ms, ...) / finish(tier, ok)
   span      prefill / prefill_chunk / decode(n, commits, spec_extra) /
             suspend(ok) / resume(ok)     — ``dur`` > 0, one per executed
             loop action, carrying the executor GapStats deltas
@@ -36,20 +48,26 @@ purely from the event stream, and the conservation gate requires exact
 agreement — any hot-path accounting drift between the loop and the trace
 is a test failure, not a silent skew in a dashboard.
 
-``export_perfetto`` writes the stream as Chrome-trace JSON (one track
-per serving instance, flow arrows linking each request's arrive →
+``export_perfetto`` writes the stream as Chrome-trace JSON (one track per
+serving instance, flow arrows linking each request's arrive →
 first-token → finish) loadable in ui.perfetto.dev or chrome://tracing.
 """
 from __future__ import annotations
 
-import dataclasses
 import json
 from collections import deque
-from typing import Any, Dict, List, NamedTuple, Optional, Sequence
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence
 
-# span kinds occupy engine time on an instance track; instants do not
+from jax.profiler import TraceAnnotation
+
+# span kinds occupy engine time on an instance track; instants do not.
+# They also name an action's kind on the profiler's ``slice/step`` span.
 SPAN_KINDS = ("prefill", "prefill_chunk", "decode", "suspend", "resume")
 DEFER_REASONS = ("pages", "states", "time", "batch", "tier")
+SPAN_PREFIX = "slice/"
+
+# jaxlib's TraceMe check: True only while a profiler session runs
+profiling = TraceAnnotation.is_enabled
 
 # the one shared payload for argless events — TraceEvent.args is always a
 # dict so consumers never None-check; READ-ONLY by the trace contract
@@ -71,20 +89,91 @@ class TraceEvent(NamedTuple):
     args: Dict[str, Any] = _NO_ARGS
 
 
-@dataclasses.dataclass
-class MetricsSnapshot:
-    """Counters/gauges sampled every ``metrics_every`` loop cycles
-    (DESIGN.md §13): the low-rate surface benchmarks and dashboards read
-    instead of grubbing through executor internals."""
-    ts: float
-    instance: str = "engine"
-    pages_in_use: int = 0
-    states_in_use: int = 0
-    resident: int = 0                 # delivered, unfinished tasks
-    defers_by_reason: Dict[str, int] = dataclasses.field(default_factory=dict)
-    spec_accept_rate: Optional[float] = None
-    suspends: int = 0
-    resumes: int = 0
+class _NoSpan:
+    """The one shared span (and action scope) of code that runs with no
+    profiler session and no recorder: every method does nothing."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set(self, args: Callable[[], Dict[str, Any]]) -> None:
+        pass
+
+    def record(self, kind: str, task_id: int, ts: float, dur: float,
+               args: Optional[Dict[str, Any]]) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
+class _Span(TraceAnnotation):
+    """A ``slice/<name>`` annotation on the profiler's host clock."""
+    __slots__ = ()
+
+    def set(self, args: Callable[[], Dict[str, Any]]) -> None:
+        """Add arguments known only once the spanned work has run."""
+        self.set_metadata(**args())
+
+
+def span(name: str, args: Optional[Callable[[], Dict[str, Any]]] = None):
+    """Context for one program phase, ``slice/<name>`` in the profiler's
+    trace. ``args`` returns the span's arguments and is called only while
+    a profiler session runs; with none, this is ``NO_SPAN``."""
+    if not profiling():
+        return NO_SPAN
+    return _Span(SPAN_PREFIX + name, **(args() if args is not None else {}))
+
+
+class _Action:
+    """One serving-loop action for both sinks: the profiler's
+    ``slice/step`` span and, with a recorder attached, its row."""
+    __slots__ = ("span", "trace", "instance", "stats", "g0")
+
+    def __init__(self, sp, trace, instance: str, stats):
+        self.span, self.trace, self.instance = sp, trace, instance
+        self.stats = stats
+        # GapStats before the scheduler's next_action, so each row's
+        # deltas include its schedule time
+        self.g0 = (stats.as_dict()
+                   if trace is not None and stats is not None else None)
+
+    def __enter__(self):
+        self.span.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return self.span.__exit__(*exc)
+
+    def record(self, kind: str, task_id: int, ts: float, dur: float,
+               args: Optional[Dict[str, Any]]) -> None:
+        """The action ran: name its kind on the span and push its row
+        (``args`` is built only when a recorder is attached)."""
+        self.span.set(lambda: {"action": kind})
+        tr = self.trace
+        if tr is None:
+            return
+        if self.g0 is not None:
+            end = self.stats.as_dict()
+            for k in self.stats.FIELDS:
+                args[k] = end[k] - self.g0[k]
+        tr.push(kind, ts, task_id, self.instance, dur, args)
+
+
+def action_span(trace, instance: str, stats, index: int):
+    """Scope of one serving-loop action (``InstanceDriver.step``): the
+    profiler's ``slice/step`` span with the step's index, and the
+    ``TraceRecorder`` row of the action with its GapStats deltas.
+    ``record`` names the action once it ran; a step that ran none records
+    nothing."""
+    sp = span("step", lambda: {"step": index})
+    if trace is None and sp is NO_SPAN:
+        return NO_SPAN
+    return _Action(sp, trace, instance, stats)
 
 
 class TraceRecorder:
@@ -93,14 +182,12 @@ class TraceRecorder:
     replay is only exact while ``dropped == 0``, so size the ring to the
     run (the default holds ~10 minutes of the paper-scale sim)."""
 
-    def __init__(self, capacity: int = 1 << 18, metrics_every: int = 0):
+    def __init__(self, capacity: int = 1 << 18):
         if capacity < 1:
             raise ValueError("capacity must be >= 1")
         self.capacity = capacity
-        self.metrics_every = metrics_every
         self._ring: deque = deque(maxlen=capacity)
         self.dropped = 0
-        self.snapshots: List[MetricsSnapshot] = []
 
     # ---- recording ----
     def emit(self, kind: str, ts: float, task_id: int = -1,
@@ -123,27 +210,6 @@ class TraceRecorder:
             self.dropped += 1
         ring.append(tuple.__new__(TraceEvent, (ts, kind, task_id,
                                                instance, dur, args)))
-
-    def sample(self, ts: float, instance: str, executor=None,
-               scheduler=None, resident: int = 0,
-               suspends: int = 0, resumes: int = 0) -> MetricsSnapshot:
-        """Build + store one MetricsSnapshot from the executor's gauge
-        surface (``Executor.trace_gauges``) and the scheduler's running
-        defer counters."""
-        gauges = executor.trace_gauges() if executor is not None else {}
-        drafted = int(getattr(executor, "drafted_tokens", 0) or 0)
-        accepted = int(getattr(executor, "accepted_tokens", 0) or 0)
-        snap = MetricsSnapshot(
-            ts=ts, instance=instance,
-            pages_in_use=int(gauges.get("pages_in_use", 0)),
-            states_in_use=int(gauges.get("states_in_use", 0)),
-            resident=resident,
-            defers_by_reason=dict(getattr(scheduler, "defers_by_reason",
-                                          None) or {}),
-            spec_accept_rate=(accepted / drafted) if drafted else None,
-            suspends=suspends, resumes=resumes)
-        self.snapshots.append(snap)
-        return snap
 
     # ---- access ----
     @property
