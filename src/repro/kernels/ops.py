@@ -48,23 +48,25 @@ def decode_attention(q, k_cache, v_cache, kv_pos, q_pos, *, window=None,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_decode_attention(q, k_pages, v_pages, page_table, q_pos, *,
-                           interpret: Optional[bool] = None):
+def paged_decode_attention(q, k_pages, v_pages, page_table, q_pos,
+                           layer=None, *, interpret: Optional[bool] = None):
     """Paged flash-decode (page table via scalar prefetch). Shapes are
-    already page-aligned by construction, so no padding path is needed."""
+    already page-aligned by construction, so no padding path is needed.
+    ``layer`` reads one layer of a stacked [L,P,Hkv,psz,W] arena."""
     return _pa.paged_decode_attention_kernel(
-        q, k_pages, v_pages, page_table, q_pos,
+        q, k_pages, v_pages, page_table, q_pos, layer,
         interpret=_auto_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def paged_verify_attention(q, k_pages, v_pages, page_table, q_start, *,
-                           interpret: Optional[bool] = None):
+def paged_verify_attention(q, k_pages, v_pages, page_table, q_start,
+                           layer=None, *, interpret: Optional[bool] = None):
     """Multi-query paged verify attention for speculative decoding
     (DESIGN.md §8): C queries per sequence at positions q_start[b]+i over
-    the paged KV arena. Shapes are page-aligned by construction."""
+    the paged KV arena. Shapes are page-aligned by construction.
+    ``layer`` reads one layer of a stacked [L,P,Hkv,psz,W] arena."""
     return _pa.paged_verify_attention_kernel(
-        q, k_pages, v_pages, page_table, q_start,
+        q, k_pages, v_pages, page_table, q_start, layer,
         interpret=_auto_interpret(interpret))
 
 

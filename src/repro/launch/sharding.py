@@ -120,7 +120,7 @@ def param_specs(cfg: ArchConfig, mesh, train: bool) -> Dict[str, Any]:
 
 
 def page_specs(cfg: ArchConfig, mesh) -> Dict[str, Any]:
-    """KV page arena [L, n_pages, Hkv, page_size, hd] (DESIGN.md §9):
+    """KV page arena [L, n_pages, Hkv, page_size, W] (DESIGN.md §9):
     per-device KV-head slabs over 'model' when the head count divides the
     axis, replicated otherwise — the same jit-input divisibility rule as
     param_specs. Page tables stay replicated host data either way: paging

@@ -183,15 +183,29 @@ def chunk_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     return attention(q, k_cache.swapaxes(1, 2), v_cache.swapaxes(1, 2), keep)
 
 
-def gather_pages(pages: jnp.ndarray, page_table: jnp.ndarray) -> jnp.ndarray:
-    """pages: [P,Hkv,psz,hd]; page_table: [B,maxp] (-1 = unused, gathered as
-    page 0 and masked by the caller via kv positions).
-    Returns the contiguous view [B,Hkv,maxp*psz,hd]."""
+def lane_pad(x: jnp.ndarray, width: int) -> jnp.ndarray:
+    """Zero-pad the last dim of ``x`` to ``width`` (the KV arena's lane
+    width, ``model.init_paged_cache``)."""
+    pad = width - x.shape[-1]
+    return x if pad == 0 else jnp.pad(x, [(0, 0)] * (x.ndim - 1)
+                                      + [(0, pad)])
+
+
+def gather_pages(pages: jnp.ndarray, page_table: jnp.ndarray,
+                 layer=None) -> jnp.ndarray:
+    """pages: [P,Hkv,psz,W], or the stacked [L,P,Hkv,psz,W] arena with
+    ``layer`` naming the layer to read (one gather — no layer slab is
+    sliced out first); page_table: [B,maxp] (-1 = unused, gathered as page
+    0 and masked by the caller via kv positions). W is the arena's lane
+    width: head_dim and its zero padding, which callers slice off.
+    Returns the contiguous view [B,Hkv,maxp*psz,W]."""
     B, maxp = page_table.shape
-    _, Hkv, psz, hd = pages.shape
-    gathered = pages[jnp.maximum(page_table, 0)]       # [B,maxp,Hkv,psz,hd]
+    Hkv, psz, W = pages.shape[-3:]
+    idx = jnp.maximum(page_table, 0)
+    gathered = pages[idx] if layer is None else pages[layer, idx]
+    # gathered: [B,maxp,Hkv,psz,W]
     return (gathered.transpose(0, 2, 1, 3, 4)
-            .reshape(B, Hkv, maxp * psz, hd))
+            .reshape(B, Hkv, maxp * psz, W))
 
 
 def paged_kv_positions(page_table: jnp.ndarray, page_size: int) -> jnp.ndarray:
@@ -205,43 +219,46 @@ def paged_kv_positions(page_table: jnp.ndarray, page_size: int) -> jnp.ndarray:
 
 def paged_decode_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                            v_pages: jnp.ndarray, page_table: jnp.ndarray,
-                           q_pos: jnp.ndarray) -> jnp.ndarray:
+                           q_pos: jnp.ndarray, layer=None) -> jnp.ndarray:
     """Single-token decode over a PAGED KV cache (pure-jnp oracle for the
     Pallas kernel in repro.kernels.paged_attention).
 
-    q: [B,Hq,hd] (RoPE'd); k/v_pages: [P,Hkv,psz,hd] — the shared page arena,
-    written at absolute positions; page_table: [B,maxp] physical page id per
-    logical page, -1 = unused; q_pos: [B] position of the newest token.
-    Attends over logical positions 0..q_pos (paged caches are append-only —
-    no ring wrap, so no sliding window here; windowed archs keep the
-    ring-slot path, DESIGN.md §3 adaptation #2).
+    q: [B,Hq,hd] (RoPE'd); k/v_pages: [P,Hkv,psz,W] — the shared page arena,
+    written at absolute positions, W >= hd (lanes past hd are the arena's
+    padding and are not read); page_table: [B,maxp] physical page id per
+    logical page, -1 = unused; q_pos: [B] position of the newest token;
+    layer: None, or the layer to read when k/v_pages are the stacked
+    [L,P,Hkv,psz,W] arena. Attends over logical positions 0..q_pos (paged
+    caches are append-only — no ring wrap, so no sliding window here;
+    windowed archs keep the ring-slot path, DESIGN.md §3 adaptation #2).
     """
-    psz = k_pages.shape[2]
-    kc = gather_pages(k_pages, page_table)
-    vc = gather_pages(v_pages, page_table)
+    psz, hd = k_pages.shape[-2], q.shape[-1]
+    kc = gather_pages(k_pages, page_table, layer)[..., :hd]
+    vc = gather_pages(v_pages, page_table, layer)[..., :hd]
     kv_pos = paged_kv_positions(page_table, psz)
     return decode_attention(q, kc, vc, kv_pos, q_pos)
 
 
 def paged_verify_attention(q: jnp.ndarray, k_pages: jnp.ndarray,
                            v_pages: jnp.ndarray, page_table: jnp.ndarray,
-                           q_start: jnp.ndarray) -> jnp.ndarray:
+                           q_start: jnp.ndarray, layer=None) -> jnp.ndarray:
     """Multi-token verify attention over a PAGED KV cache: the k-query
     generalization of ``paged_decode_attention`` (pure-jnp oracle for the
     Pallas kernel in repro.kernels.paged_attention), used by speculative
     decoding's draft-verify step (DESIGN.md §8).
 
     q: [B,C,Hq,hd] — the C=depth+1 verify queries, RoPE'd at absolute
-    positions ``q_start[b]+i``; k/v_pages: [P,Hkv,psz,hd] with the verify
+    positions ``q_start[b]+i``; k/v_pages: [P,Hkv,psz,W] with the verify
     window's own KV already scattered in; page_table: [B,maxp] physical
     page per logical page, -1 = unused; q_start: [B] position of the first
-    verify query. Query i attends over logical positions 0..q_start+i
-    (causal within the speculative window, full prefix before it).
+    verify query; layer: as in ``paged_decode_attention``. Query i attends
+    over logical positions 0..q_start+i (causal within the speculative
+    window, full prefix before it).
     """
-    psz = k_pages.shape[2]
+    psz, hd = k_pages.shape[-2], q.shape[-1]
     C = q.shape[1]
-    kc = gather_pages(k_pages, page_table)
-    vc = gather_pages(v_pages, page_table)
+    kc = gather_pages(k_pages, page_table, layer)[..., :hd]
+    vc = gather_pages(v_pages, page_table, layer)[..., :hd]
     kv_pos = paged_kv_positions(page_table, psz)
     q_pos = q_start[:, None] + jnp.arange(C, dtype=q_start.dtype)
     return chunk_decode_attention(q, kc, vc, kv_pos, q_pos)

@@ -388,6 +388,43 @@ def prefill_chunk(cfg: ArchConfig, params: Params, cache: Cache,
     return unembed(cfg, params, x[:, -1]), new_cache
 
 
+def _write_tokens(arena: jnp.ndarray, layer, page_table: jnp.ndarray,
+                 start: jnp.ndarray, new: jnp.ndarray,
+                 valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Write row b's run of C new K or V rows, ``new[b]`` [C,Hkv,hd], at
+    logical positions start[b]..start[b]+C-1 of layer ``layer`` of the
+    stacked arena [L,P,Hkv,psz,W] (rows with ``valid[b]`` false write
+    nothing; lanes hd..W-1 are written as zeros). Returns the updated arena.
+
+    Whole pages move: each page a run touches is gathered, the run's rows
+    are merged in, and the page is scattered back to where it came from.
+    A page-shaped update keeps the arena in its own row-major layout, the
+    one the paged kernel reads, so inside a compiled step whose arena is
+    donated the write is in place; a scatter of single token rows would
+    make the TPU compiler relayout the whole arena around it. Pages the
+    run does not reach and untabled pages are dropped, so no two updates
+    of one scatter name the same page."""
+    B, C = new.shape[:2]
+    n_pages, _, psz = arena.shape[1:4]
+    maxp = page_table.shape[1]
+    touch = (C + 2 * psz - 2) // psz               # pages a run can span
+    lp = start[:, None] // psz + jnp.arange(touch)[None, :]        # [B,T]
+    phys = page_table[jnp.arange(B)[:, None], jnp.minimum(lp, maxp - 1)]
+    idx = (lp[..., None] * psz + jnp.arange(psz)
+           - start[:, None, None])                 # [B,T,psz] run index
+    inrun = (idx >= 0) & (idx < C)
+    ok = (lp < maxp) & (phys >= 0) & inrun.any(-1)
+    if valid is not None:
+        ok &= valid[:, None]
+    cur = arena[layer, jnp.where(ok, phys, 0)]     # [B,T,Hkv,psz,W]
+    rows = L.lane_pad(new[jnp.arange(B)[:, None, None],
+                          jnp.clip(idx, 0, C - 1)], arena.shape[-1])
+    upd = jnp.where(inrun[:, :, None, :, None],
+                    rows.swapaxes(2, 3).astype(arena.dtype), cur)
+    return arena.at[layer, jnp.where(ok, phys, n_pages)].set(upd,
+                                                            mode="drop")
+
+
 def prefill_chunk_paged(cfg: ArchConfig, params: Params, pages: Cache,
                         page_table: jnp.ndarray, lengths: jnp.ndarray,
                         tokens: jnp.ndarray,
@@ -398,37 +435,34 @@ def prefill_chunk_paged(cfg: ArchConfig, params: Params, pages: Cache,
     tokens: [B,C] appended at logical positions ``lengths[b]+i``; the page
     table must already cover lengths+C tokens (the pool extends BEFORE the
     chunk — incremental allocation, not a peak reservation). The chunk's KV
-    is scattered into its pages, then its queries attend over the gathered
-    page view (kv-position masking, or the Pallas chunk kernel — untabled
-    entries sit at logical positions beyond the chunk end, so causal masking
-    covers them). Returns (logits of the chunk's last position [B,V], new
-    pages). Lengths/page tables are host-side pool state — caller advances.
+    is written into its pages of the arena, which every layer carries and
+    returns whole (``_write_tokens``), then its queries attend over the
+    gathered page view (kv-position masking, or the Pallas chunk kernel —
+    untabled entries sit at logical positions beyond the chunk end, so
+    causal masking covers them). Returns (logits of the chunk's last
+    position [B,V], new pages). Lengths/page tables are host-side pool
+    state — caller advances.
     """
     assert cfg.causal and cfg.has_attention and not cfg.has_ssm
     B, C = tokens.shape
-    n_pages, psz = pages["k_pages"].shape[1], pages["k_pages"].shape[3]
+    psz = pages["k_pages"].shape[3]
     x = params["embed"][tokens]                    # [B,C,D]
     q_pos = lengths[:, None] + jnp.arange(C, dtype=lengths.dtype)  # [B,C]
-    logical = q_pos // psz
-    off = q_pos % psz
-    barr = jnp.arange(B)[:, None]
-    pt_row = page_table[barr, logical]             # [B,C] phys page per token
-    # out-of-bounds index => scatter dropped (untabled rows)
-    phys = jnp.where(pt_row >= 0, pt_row, n_pages)
 
-    def body(x, xs):
-        bp, lc = xs
-        kp, vp = lc["k"], lc["v"]                  # [P,Hkv,psz,hd]
+    def body(carry, xs):
+        x, kp, vp = carry                          # kp/vp: whole arena
+        bp, l = xs
         h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
         q = (h @ bp["wq"]).reshape(B, C, cfg.n_heads, cfg.head_dim)
         k = (h @ bp["wk"]).reshape(B, C, cfg.n_kv_heads, cfg.head_dim)
         v = (h @ bp["wv"]).reshape(B, C, cfg.n_kv_heads, cfg.head_dim)
         q = shard(L.apply_rope(q, q_pos, cfg.rope_theta), ("b", None, "m", None))
         k = L.apply_rope(k, q_pos, cfg.rope_theta)
-        kp = kp.at[phys, :, off].set(k, mode="drop")
-        vp = vp.at[phys, :, off].set(v, mode="drop")
-        kc = L.gather_pages(kp, page_table)        # [B,Hkv,maxp*psz,hd]
-        vc = L.gather_pages(vp, page_table)
+        kp = _write_tokens(kp, l, page_table, lengths, k)
+        vp = _write_tokens(vp, l, page_table, lengths, v)
+        hd = cfg.head_dim
+        kc = L.gather_pages(kp, page_table, l)[..., :hd]  # [B,Hkv,maxp*psz,hd]
+        vc = L.gather_pages(vp, page_table, l)[..., :hd]
         if use_kernel:
             from repro.kernels import ops as _kops
             a = _kops.flash_prefill_chunk(q, kc.swapaxes(1, 2),
@@ -438,13 +472,12 @@ def prefill_chunk_paged(cfg: ArchConfig, params: Params, pages: Cache,
             a = L.chunk_decode_attention(q, kc, vc, kv_pos, q_pos)
         x = x + a.reshape(B, C, cfg.q_dim) @ bp["wo"]
         f_out, _ = _ffn(cfg, bp, x, opts.moe_impl)
-        return x + f_out, {"k": kp, "v": vp}
+        return (x + f_out, kp, vp), None
 
-    layer_pages = {"k": pages["k_pages"], "v": pages["v_pages"]}
-    x, new_layer_pages = jax.lax.scan(body, x, (params["blocks"], layer_pages),
-                                      unroll=opts.unroll)
-    return unembed(cfg, params, x[:, -1]), {"k_pages": new_layer_pages["k"],
-                                            "v_pages": new_layer_pages["v"]}
+    (x, kp, vp), _ = jax.lax.scan(
+        body, (x, pages["k_pages"], pages["v_pages"]),
+        (params["blocks"], jnp.arange(cfg.n_layers)), unroll=opts.unroll)
+    return unembed(cfg, params, x[:, -1]), {"k_pages": kp, "v_pages": vp}
 
 
 # ------------------------------------------------------- speculative verify
@@ -460,7 +493,7 @@ def verify_step_paged(cfg: ArchConfig, params: Params, pages: Cache,
     appended at logical positions ``lengths[b]+i``; the page table must
     already cover lengths+C tokens (the pool extends BEFORE the step; the
     caller rolls back pages for rejected drafts with pool.truncate after
-    acceptance). The window's KV is scattered into its pages, then every
+    acceptance). The window's KV is written into its pages, then every
     query attends over the gathered page view with the causal staircase
     (query i sees positions 0..lengths[b]+i) — or the Pallas
     ``paged_verify_attention`` kernel with ``use_kernel=True``.
@@ -475,42 +508,34 @@ def verify_step_paged(cfg: ArchConfig, params: Params, pages: Cache,
     """
     assert cfg.causal and cfg.has_attention and not cfg.has_ssm
     B, C = tokens.shape
-    n_pages, psz = pages["k_pages"].shape[1], pages["k_pages"].shape[3]
     x = params["embed"][tokens]                    # [B,C,D]
     q_pos = lengths[:, None] + jnp.arange(C, dtype=lengths.dtype)  # [B,C]
-    logical = q_pos // psz
-    off = q_pos % psz
-    barr = jnp.arange(B)[:, None]
-    pt_row = page_table[barr, logical]             # [B,C] phys page per token
-    # out-of-bounds index => scatter dropped (untabled rows)
-    phys = jnp.where(pt_row >= 0, pt_row, n_pages)
 
-    def body(x, xs):
-        bp, lc = xs
-        kp, vp = lc["k"], lc["v"]                  # [P,Hkv,psz,hd]
+    def body(carry, xs):
+        x, kp, vp = carry                          # kp/vp: whole arena
+        bp, l = xs
         h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
         q = (h @ bp["wq"]).reshape(B, C, cfg.n_heads, cfg.head_dim)
         k = (h @ bp["wk"]).reshape(B, C, cfg.n_kv_heads, cfg.head_dim)
         v = (h @ bp["wv"]).reshape(B, C, cfg.n_kv_heads, cfg.head_dim)
         q = shard(L.apply_rope(q, q_pos, cfg.rope_theta), ("b", None, "m", None))
         k = L.apply_rope(k, q_pos, cfg.rope_theta)
-        kp = kp.at[phys, :, off].set(k, mode="drop")
-        vp = vp.at[phys, :, off].set(v, mode="drop")
+        kp = _write_tokens(kp, l, page_table, lengths, k)
+        vp = _write_tokens(vp, l, page_table, lengths, v)
         if use_kernel:
             from repro.kernels import ops as _kops
-            a = _kops.paged_verify_attention(q, kp, vp, page_table, lengths)
+            a = _kops.paged_verify_attention(q, kp, vp, page_table, lengths, l)
         else:
-            a = L.paged_verify_attention(q, kp, vp, page_table, lengths)
+            a = L.paged_verify_attention(q, kp, vp, page_table, lengths, l)
         x = x + a.reshape(B, C, cfg.q_dim) @ bp["wo"]
         f_out, _ = _ffn(cfg, bp, x, "dense" if cfg.block_kind != "moe"
                         else opts.moe_impl)
-        return x + f_out, {"k": kp, "v": vp}
+        return (x + f_out, kp, vp), None
 
-    layer_pages = {"k": pages["k_pages"], "v": pages["v_pages"]}
-    x, new_layer_pages = jax.lax.scan(body, x, (params["blocks"], layer_pages),
-                                      unroll=opts.unroll)
-    return unembed(cfg, params, x), {"k_pages": new_layer_pages["k"],
-                                     "v_pages": new_layer_pages["v"]}
+    (x, kp, vp), _ = jax.lax.scan(
+        body, (x, pages["k_pages"], pages["v_pages"]),
+        (params["blocks"], jnp.arange(cfg.n_layers)), unroll=opts.unroll)
+    return unembed(cfg, params, x), {"k_pages": kp, "v_pages": vp}
 
 
 # ------------------------------------------------------------------ decode
@@ -591,11 +616,21 @@ def decode_step(cfg: ArchConfig, params: Params, cache: Cache,
 
 # ------------------------------------------------------------ paged decode
 
+ARENA_LANES = 128           # a TPU vector register's lane count
+
+
 def init_paged_cache(cfg: ArchConfig, n_pages: int, page_size: int,
                      dtype=jnp.float32) -> Cache:
-    """Shared KV page arena: k/v_pages [L, n_pages, Hkv, page_size, hd].
-    Page ownership lives in serving.kv_pool.KVPagePool; sequences address the
-    arena through per-step [B, max_pages] page tables (decode_step_paged).
+    """Shared KV page arena: k/v_pages [L, n_pages, Hkv, page_size, W],
+    W = hd rounded up to whole 128-lane rows (``ARENA_LANES``; lanes hd..W-1
+    stay zero). With a lane-aligned minor dim the TPU's default layout for
+    the arena is row-major, a page's (page_size, W) block contiguous — the
+    layout the paged kernel reads — so the compiled steps take, update and
+    return the arena in place with no relayout (DESIGN.md §3, arena
+    ownership); at hd 64 the TPU's default layout would put the pages
+    dimension minor instead. Page ownership lives in
+    serving.kv_pool.KVPagePool; sequences address the arena through
+    per-step [B, max_pages] page tables (decode_step_paged).
 
     Attention-free (pure SSM) archs get a zero-width arena (Hkv = hd = 0):
     the page table stays the logical token-length ledger for every arch
@@ -604,7 +639,8 @@ def init_paged_cache(cfg: ArchConfig, n_pages: int, page_size: int,
     assert cfg.has_attention or cfg.has_ssm, "arch has no decode cache"
     hkv = cfg.n_kv_heads if cfg.has_attention else 0
     hd = cfg.head_dim if cfg.has_attention else 0
-    shape = (cfg.n_layers, n_pages, hkv, page_size, hd)
+    width = -(-hd // ARENA_LANES) * ARENA_LANES
+    shape = (cfg.n_layers, n_pages, hkv, page_size, width)
     return {"k_pages": jnp.zeros(shape, dtype),
             "v_pages": jnp.zeros(shape, dtype)}
 
@@ -643,10 +679,14 @@ def decode_step_paged(cfg: ArchConfig, params: Params, pages: Cache,
     page (-1 unused; row b must cover lengths[b]+1 tokens — the pool
     extends BEFORE the step); lengths: [B] cached tokens per row (the new
     token is written at logical position lengths[b]); tokens: [B] int32;
-    active: [B] bool — inactive rows write nothing (their scatter index is
-    out-of-bounds and dropped) and their logits are garbage to be ignored;
+    active: [B] bool — inactive rows write nothing and their logits are
+    garbage to be ignored;
     state_slots: [B] state-arena slot per row (-1 pad rows), required for
     SSM/hybrid archs.
+
+    Every arena rides the layer scan's carry whole: layer l writes and
+    reads its part in place, so a caller that donates ``pages`` gets the
+    same buffers back (DESIGN.md §3, arena ownership).
 
     Returns (logits [B,V], new pages). Lengths/page tables/slots are
     host-side pool state, not device state — the caller advances them.
@@ -656,71 +696,60 @@ def decode_step_paged(cfg: ArchConfig, params: Params, pages: Cache,
     B = tokens.shape[0]
     if active is None:
         active = jnp.ones((B,), bool)
-    n_pages, psz = pages["k_pages"].shape[1], pages["k_pages"].shape[3]
     x = params["embed"][tokens]                    # [B,D]
     q_pos = lengths
-    logical = q_pos // psz
-    off = q_pos % psz
-    pt_row = page_table[jnp.arange(B), logical]    # phys page of the new token
-    # out-of-bounds index => scatter dropped (inactive / untabled rows)
-    phys = jnp.where(active & (pt_row >= 0), pt_row, n_pages)
     if cfg.has_ssm:
         n_slots = pages["ssm_state"].shape[1]
         slot_rd = jnp.clip(state_slots, 0, n_slots - 1)  # pad rows read slot 0
         slot_wr = jnp.where(active & (state_slots >= 0), state_slots, n_slots)
 
-    def body(x, xs):
-        bp, lc = xs
+    def body(carry, xs):
+        x, arena = carry                           # the whole arena dict
+        bp, l = xs
+        arena = dict(arena)
         h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
-        new_lc: Dict[str, Any] = {}
         parts = []
         if cfg.has_attention:
-            kp, vp = lc["k"], lc["v"]              # [P,Hkv,psz,hd]
             q = (h @ bp["wq"]).reshape(B, cfg.n_heads, cfg.head_dim)
             k = (h @ bp["wk"]).reshape(B, cfg.n_kv_heads, cfg.head_dim)
             v = (h @ bp["wv"]).reshape(B, cfg.n_kv_heads, cfg.head_dim)
             q = shard(L.apply_rope(q[:, None], q_pos[:, None],
                                    cfg.rope_theta)[:, 0], ("b", "m", None))
             k = L.apply_rope(k[:, None], q_pos[:, None], cfg.rope_theta)[:, 0]
-            kp = kp.at[phys, :, off].set(k, mode="drop")
-            vp = vp.at[phys, :, off].set(v, mode="drop")
+            # inactive and untabled rows write nothing
+            kp = _write_tokens(arena["k_pages"], l, page_table, q_pos,
+                               k[:, None], active)
+            vp = _write_tokens(arena["v_pages"], l, page_table, q_pos,
+                               v[:, None], active)
             if use_kernel:
                 from repro.kernels import ops as _kops
-                a = _kops.paged_decode_attention(q, kp, vp, page_table, q_pos)
+                a = _kops.paged_decode_attention(q, kp, vp, page_table, q_pos,
+                                                 l)
             else:
-                a = L.paged_decode_attention(q, kp, vp, page_table, q_pos)
+                a = L.paged_decode_attention(q, kp, vp, page_table, q_pos, l)
             parts.append(a.reshape(B, cfg.q_dim) @ bp["wo"])
-            new_lc["k"], new_lc["v"] = kp, vp
-        else:
-            new_lc["k"], new_lc["v"] = lc["k"], lc["v"]  # zero-width arena
+            arena["k_pages"], arena["v_pages"] = kp, vp
+        # attention-free archs carry their zero-width k/v arena untouched
         if cfg.has_ssm:
             sp = SSM.SSMParams(*[bp["ssm"][i] for i in range(len(bp["ssm"]))])
-            hS = lc["s"][slot_rd]                  # [B,H,P,N]
-            cS = lc["c"][slot_rd]                  # [B,C,K-1]
+            hS = arena["ssm_state"][l, slot_rd]    # [B,H,P,N]
+            cS = arena["conv_state"][l, slot_rd]   # [B,C,K-1]
             s_out, hS2, cS2 = SSM.ssm_mixer_step(
                 sp, h, cfg.ssm_inner, cfg.ssm_state, cfg.ssm_head_dim,
                 hS, cS)
             parts.append(s_out)
             # inactive/pad rows scatter out of bounds and are dropped
-            new_lc["s"] = lc["s"].at[slot_wr].set(hS2, mode="drop")
-            new_lc["c"] = lc["c"].at[slot_wr].set(
-                cS2.astype(lc["c"].dtype), mode="drop")
+            arena["ssm_state"] = arena["ssm_state"].at[l, slot_wr].set(
+                hS2, mode="drop")
+            arena["conv_state"] = arena["conv_state"].at[l, slot_wr].set(
+                cS2.astype(arena["conv_state"].dtype), mode="drop")
         mixer = parts[0] if len(parts) == 1 else 0.5 * (parts[0] + parts[1])
         x = x + mixer
         f_out, _ = _ffn(cfg, bp, x, "dense" if cfg.block_kind != "moe"
                         else opts.moe_impl)
-        return x + f_out, new_lc
+        return (x + f_out, arena), None
 
-    layer_pages = {"k": pages["k_pages"], "v": pages["v_pages"]}
-    if cfg.has_ssm:
-        layer_pages["s"] = pages["ssm_state"]
-        layer_pages["c"] = pages["conv_state"]
-    x, new_layer_pages = jax.lax.scan(body, x, (params["blocks"], layer_pages),
-                                      unroll=opts.unroll)
-    logits = unembed(cfg, params, x)
-    new_pages = {"k_pages": new_layer_pages["k"],
-                 "v_pages": new_layer_pages["v"]}
-    if cfg.has_ssm:
-        new_pages["ssm_state"] = new_layer_pages["s"]
-        new_pages["conv_state"] = new_layer_pages["c"]
-    return logits, new_pages
+    (x, new_pages), _ = jax.lax.scan(
+        body, (x, dict(pages)),
+        (params["blocks"], jnp.arange(cfg.n_layers)), unroll=opts.unroll)
+    return unembed(cfg, params, x), new_pages

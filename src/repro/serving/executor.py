@@ -847,16 +847,22 @@ class PagedJaxExecutor(Executor):
         (logits, *extras, pages) output to (replicated..., canonical page
         sharding), keeping self.pages stable across steps. ``extra_repl``
         counts replicated outputs between logits and pages (the decode
-        step's fused argmax)."""
+        step's fused argmax).
+
+        A ``pages_out`` step owns the arena: argument 1 (the pages) is
+        donated, so the step updates the arena in place and the arrays
+        passed in are deleted by the call. ``self.pages`` is the only live
+        reference to the arena (DESIGN.md §3)."""
         jax = self.jax
-        if self.mesh is None:
-            return jax.jit(fn).lower(*example_args).compile()
-        from repro.models.partitioning import activation_partitioning
+        donate = (1,) if pages_out else ()
         out_sh = ((self._repl_sh,) * (1 + extra_repl) + (self._page_sh,)
-                  if pages_out else None)
+                  if pages_out and self.mesh is not None else None)
+        step = jax.jit(fn, out_shardings=out_sh, donate_argnums=donate)
+        if self.mesh is None:
+            return step.lower(*example_args).compile()
+        from repro.models.partitioning import activation_partitioning
         with self.mesh, activation_partitioning(self._batch_axes, "model"):
-            return jax.jit(fn, out_shardings=out_sh).lower(
-                *example_args).compile()
+            return step.lower(*example_args).compile()
 
     # -- async pipelining (DESIGN.md §10) --
     def _async_on(self) -> bool:
@@ -1459,13 +1465,15 @@ class PagedJaxExecutor(Executor):
         span = n_alloc * psz
         idx = jnp.asarray(phys, jnp.int32)
         if self.cfg.has_attention:
+            from repro.models.layers import lane_pad
             for name, src in (("k_pages", cache1["k"]),
                               ("v_pages", cache1["v"])):
-                # [L,1,Hkv,max_seq,hd] -> [L,n_alloc,Hkv,psz,hd]
+                # [L,1,Hkv,max_seq,hd] -> [L,n_alloc,Hkv,psz,W]
                 view = (src[:, 0, :, :span, :]
                         .reshape(src.shape[0], src.shape[2], n_alloc, psz, -1)
                         .swapaxes(1, 2))
-                self.pages[name] = self.pages[name].at[:, idx].set(view)
+                self.pages[name] = self.pages[name].at[:, idx].set(
+                    lane_pad(view, self.pages[name].shape[-1]))
         if self.states is not None:
             # splice the prefill's final recurrent state into the task's
             # slot — the whole per-task state is one fixed-size "page"

@@ -28,7 +28,7 @@ re-allocates fresh device pages for exactly the swapped-out positions so
 the executor can restore the contents.
 
 Pure bookkeeping — no jax. The executor owns the physical page arrays
-(``k_pages``/``v_pages``: [L, n_pages, Hkv, page_size, hd]); this class
+(``k_pages``/``v_pages``: [L, n_pages, Hkv, page_size, W]); this class
 owns which page ids belong to which task. A slot array is the degenerate
 pool with ``page_size == max_seq`` and one page per task, which is how the
 kv_pressure benchmark compares the two layouts at equal bytes.

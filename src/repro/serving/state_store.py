@@ -149,9 +149,11 @@ def state_bytes_per_task(cfg) -> int:
 
 
 def kv_bytes_per_page(cfg, page_size: int) -> int:
-    """Device bytes of one KV page across all layers (k + v, f32). Zero
-    for attention-free architectures (their page table is a pure logical
-    ledger, see DESIGN.md §12)."""
+    """Device bytes of one KV page across all layers (k + v, f32), as
+    K/V payload: the arena's padding of head_dim to whole 128-lane rows
+    (``model.init_paged_cache``) is not counted. Zero for attention-free
+    architectures (their page table is a pure logical ledger, see
+    DESIGN.md §12)."""
     if not cfg.has_attention:
         return 0
     return cfg.n_layers * 2 * cfg.n_kv_heads * page_size * cfg.head_dim * 4

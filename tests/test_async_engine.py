@@ -147,8 +147,12 @@ def test_suspend_resume_mid_stream_matches(setup):
         for _ in range(4):
             ex.decode(tasks)
         ex.suspend(tasks[0])
+        held = list(ex.pages.values())
         for _ in range(3):
             ex.decode(tasks[1:])
+        # the decodes consumed the arena the suspend's (possibly still
+        # materializing) page slabs were gathered from
+        assert all(a.is_deleted() for a in held)
         ex.resume(tasks[0])
         for _ in range(3):
             ex.decode(tasks)
@@ -173,9 +177,13 @@ def test_suspend_during_in_flight_decode_lands_after_commit(setup):
     for t in tasks:
         exB.prefill(t)
     pre_len = exB.pool.length(tasks[0].task_id)
+    held = list(exB.pages.values())
     for _ in range(3):
         exB.decode(tasks)
     assert len(exB._queue) > 0          # decodes genuinely in flight
+    # each in-flight step consumed its predecessor's arena at dispatch;
+    # what the queue holds for commit is logits and tokens, never pages
+    assert all(a.is_deleted() for a in held)
     exB.suspend(tasks[0])
     assert len(exB._queue) == 0         # suspend committed them first
     # every dispatched decode landed in history BEFORE the pages left

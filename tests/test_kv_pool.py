@@ -247,6 +247,43 @@ def test_paged_kernel_page_boundary_masking():
                                    rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("kind", ["decode", "verify"])
+@pytest.mark.parametrize("layer", [0, 2, 4])
+@pytest.mark.parametrize("width", [32, 128], ids=["unpadded", "lane_padded"])
+def test_paged_kernels_read_one_layer_of_the_stacked_arena(kind, layer,
+                                                           width):
+    """Given the whole [L,P,Hkv,psz,W] arena and a layer index, the paged
+    kernels read that layer's pages: equal to the oracle on pages[l]. With
+    W past head_dim (the arena's lane padding, here holding garbage in V
+    and zeros in K, as written) only the first head_dim lanes count."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kernels import ops, ref
+
+    L, P, Hkv, psz, hd, Hq, B, C = 5, 12, 2, 8, 32, 4, 3, 3
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    kp = jax.random.normal(ks[1], (L, P, Hkv, psz, width))
+    kp = kp.at[..., hd:].set(0.0)
+    vp = jax.random.normal(ks[2], (L, P, Hkv, psz, width))
+    pt = jnp.array([[3, 5, -1, -1], [0, -1, -1, -1], [7, 2, 9, 1]], jnp.int32)
+    pos = jnp.array([12, 4, 27], jnp.int32)
+    lyr = jnp.int32(layer)
+    if kind == "decode":
+        q = jax.random.normal(ks[0], (B, Hq, hd))
+        out = ops.paged_decode_attention(q, kp, vp, pt, pos, lyr,
+                                         interpret=True)
+        want = ref.paged_decode_attention_ref(q, kp[layer, ..., :hd],
+                                              vp[layer, ..., :hd], pt, pos)
+    else:
+        q = jax.random.normal(ks[0], (B, C, Hq, hd))
+        out = ops.paged_verify_attention(q, kp, vp, pt, pos, lyr,
+                                         interpret=True)
+        want = ref.paged_verify_attention_ref(q, kp[layer, ..., :hd],
+                                              vp[layer, ..., :hd], pt, pos)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
 # --------------------------------------------------------- paged executor
 
 @pytest.fixture(scope="module")
@@ -309,8 +346,13 @@ def test_paged_executor_no_leaks_across_serving_run(tiny_cfg):
 
     ex = PagedJaxExecutor(tiny_cfg, n_pages=8, page_size=16, max_seq=64,
                           max_batch=4)
+    before = list(ex.pages.values())
     lat = ex.latency_model()
     assert ex.pool.used_pages == 0       # latency probes released their pages
+    # the probe's decodes consumed the arena they were given; the engine
+    # serves on from the one they returned
+    assert all(a.is_deleted() for a in before)
+    assert not any(a.is_deleted() for a in ex.pages.values())
     tasks = [control_task(output_len=6, prompt_len=12),
              qa_task(arrival_ms=1.0, output_len=8, prompt_len=16),
              qa_task(arrival_ms=2.0, output_len=8, prompt_len=16),

@@ -376,7 +376,11 @@ def test_paged_executor_suspend_resume_matches_logits(tiny_cfg):
     step([0, 1])
     exA.suspend(tasks[0])
     assert exA.arena.bytes_held > 0
+    held = list(exA.pages.values())
     step([1])
+    # the step consumed the arena the suspend read its pages from; the
+    # host copies are what resume restores
+    assert all(a.is_deleted() for a in held)
     exA.resume(tasks[0])
     step([0, 1])
     step([0])

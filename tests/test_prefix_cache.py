@@ -319,6 +319,52 @@ def test_prefix_shared_prefill_decode_logits_match_unshared(tiny_cfg):
     exB.pool.check()
 
 
+def test_copy_on_write_fork_before_a_donated_step(tiny_cfg):
+    """A decode that writes into a shared page forks it first: the page is
+    copied eagerly (out of place) and the arena re-pinned to its canonical
+    format, then the compiled step consumes that new arena. The sharer
+    decodes as an engine holding the same tokens privately does, and the
+    owner's page is left as it was."""
+    from repro.serving.executor import PagedJaxExecutor
+
+    kw = dict(n_pages=16, page_size=8, max_seq=64, seed=0, max_batch=4)
+    ex = PagedJaxExecutor(tiny_cfg, **kw)
+    solo = PagedJaxExecutor(tiny_cfg, params=ex.params, **kw)
+    ref = PagedJaxExecutor(tiny_cfg, params=ex.params, **kw)
+    owner = qa_task(output_len=4, prompt_len=16)
+    sharer = qa_task(output_len=4, prompt_len=12)
+    ex.prefill(owner)
+    solo.prefill(owner)
+    prompt = ex._task_tokens(owner)
+    # the sharer holds the owner's first 12 tokens in the owner's pages,
+    # so its next token lands inside a shared page
+    ex.pool.share(sharer.task_id, ex.pool.page_table(owner.task_id), 16)
+    ex.pool.truncate(sharer.task_id, 12)
+    ex._last_tok[sharer.task_id] = int(prompt[0, 12])
+    ref._toks_memo[sharer.task_id] = prompt[:, :12]
+    ref.prefill(sharer)
+    ref._last_tok[sharer.task_id] = int(prompt[0, 12])
+    owner_pages = ex.pool.page_table(owner.task_id)
+
+    before = list(ex.pages.values())
+    ex.decode([sharer])
+    ref.decode([sharer])
+    # the fork's copy is out of place: the step consumed the forked arena,
+    # not the one held here
+    assert not any(a.is_deleted() for a in before)
+    assert ex.pool.page_table(sharer.task_id)[1] != owner_pages[1]
+    np.testing.assert_allclose(ex.last_logits, ref.last_logits,
+                               atol=1e-5, rtol=0)
+    ex.decode([owner])
+    solo.decode([owner])
+    np.testing.assert_allclose(ex.last_logits, solo.last_logits,
+                               atol=1e-5, rtol=0)
+    for t in (owner, sharer):
+        ex.release(t)
+    ex.pool.check()
+    assert ex.pool.used_pages == 0
+
+
 def test_prefix_shared_chunked_prefill_starts_at_first_uncached_chunk(tiny_cfg):
     from repro.serving.executor import PagedJaxExecutor
 

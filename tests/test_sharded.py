@@ -51,9 +51,10 @@ def test_page_arena_sharded_over_kv_heads(mesh4, shard_setup):
     assert len(shards) == 4
     assert len({s.device for s in shards}) == 4
     L, n_pages = exB.pages["k_pages"].shape[:2]
+    width = -(-cfg.head_dim // 128) * 128     # head_dim, lane-padded
     for s in shards:
         assert s.data.shape == (L, n_pages, cfg.n_kv_heads // 4,
-                                exB.page_size, cfg.head_dim)
+                                exB.page_size, width)
 
 
 def test_mesh_engine_inits_params_in_their_shardings(mesh4, shard_setup):

@@ -127,3 +127,54 @@ def test_full_width_decode_step_compiles(one_chip, use_kernel, monkeypatch):
         _spec(one_chip, (B,), jnp.int32), _spec(one_chip, (B,), jnp.int32),
         _spec(one_chip, (B,), jnp.bool_))
     assert ("tpu_custom_call" in hlo) == use_kernel
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_served_steps_update_the_arena_in_place(one_chip, kind,
+                                                       monkeypatch):
+    """The benchmark cell's engine (32 rows, 2048 pages, max_seq 1024,
+    128-token chunks, both kernels) as the executor builds it: the arena
+    donated, at the TPU's default layout for its lane-padded shape. That
+    layout is row-major, the one the paged kernel reads (at head_dim 64
+    unpadded it would put the pages dimension minor); both arenas alias
+    outputs, no arena-shaped copy is made and no temp buffer is as large
+    as one arena — no step relayouts or rebuilds the arena."""
+    import re
+
+    monkeypatch.setattr(ops, "_auto_interpret",
+                        lambda interpret: bool(interpret))
+    n_pages, maxp, B, C = 2048, 1024 // PAGE, 32, 128
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                            tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: M.init_params(CFG, jax.random.PRNGKey(0))))
+    pages = shapes(jax.eval_shape(
+        lambda: M.init_paged_cache(CFG, n_pages, PAGE)))
+    if kind == "decode":
+        fn = lambda p, g, pt, ln, tk: M.decode_step_paged(  # noqa: E731
+            CFG, p, g, pt, ln, tk, use_kernel=True)
+        rows, toks = B, (B,)
+    else:
+        fn = lambda p, g, pt, ln, tk: M.prefill_chunk_paged(  # noqa: E731
+            CFG, p, g, pt, ln, tk, use_kernel=True)
+        rows, toks = 1, (1, C)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pages, _spec(one_chip, (rows, maxp), jnp.int32),
+        _spec(one_chip, (rows,), jnp.int32),
+        _spec(one_chip, toks, jnp.int32)).compile()
+    arena = pages["k_pages"]
+    assert arena.shape[-1] == 128            # head_dim 64, lane-padded
+    for name in ("k_pages", "v_pages"):
+        fmt = compiled.input_formats[0][1][name]
+        assert fmt.layout.major_to_minor == tuple(range(5)), name
+    arena_bytes = arena.size * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * arena_bytes
+    assert mem.temp_size_in_bytes < arena_bytes
+    hlo = compiled.as_text()
+    shape = "f32[%s]" % ",".join(map(str, arena.shape))
+    assert not re.search(re.escape(shape) + r"\{[^}]*\} copy\(", hlo)
+    assert "tpu_custom_call" in hlo
